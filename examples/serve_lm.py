@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import reduced_config
 from repro.models import build
 from repro.serve import (ContinuousConfig, ContinuousScheduler, Engine,
@@ -55,6 +56,7 @@ def main() -> None:
                          "decode over a paged KV pool) instead of the "
                          "batch-1 front-end")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduced_config(args.arch)
     model = build(cfg)

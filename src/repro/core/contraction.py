@@ -71,8 +71,13 @@ def default_backend() -> str:
 
 
 def kernel_backend() -> bool:
-    """Whether auto-dispatch targets the hand-scheduled kernels (TPU)."""
-    return jax.default_backend() == "tpu"
+    """Whether auto-dispatch targets the hand-scheduled kernels: on a TPU,
+    outside a device mesh. A Pallas kernel is a one-device program with no
+    partitioning rule and no transpose rule, so the sharded (and
+    differentiated) programs that run under ``repro.parallel.mesh.use_mesh``
+    — the training driver's — take the library lowerings."""
+    from repro.parallel.mesh import current_mesh
+    return jax.default_backend() == "tpu" and current_mesh() is None
 
 
 # ---------------------------------------------------------------------------

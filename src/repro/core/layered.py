@@ -136,7 +136,10 @@ class _PackedCommon:
     @staticmethod
     def _pack_pair(w: jnp.ndarray, fmt: TileFormat, backend: str,
                    grouped: bool):
-        """One (packed, scales-or-None) pair via the format-driven packers."""
+        """One (packed, scales-or-None) pair via the format-driven packers:
+        the Pallas packer on the kernel backend (on a TPU a packing failure
+        raises — no reference packer stands in for the device), the jnp
+        reference packer elsewhere."""
         if grouped:
             packer = (pack_mod.pack_b_grouped if backend == "pallas"
                       else ref.pack_b_grouped_ref)
@@ -192,13 +195,13 @@ class PackedWeight(_PackedCommon):
                                  scale_granularity=gran)
         cls._check_quantize_plan(plan, quantize)
         fmt = plan.b_format
+        be = backend or default_backend()
         if w.ndim == 3:
-            # Load-time packing of the whole layer stack (jnp packer: runs
-            # once, identical buffer layout to the Pallas packer's).
+            # Load-time packing of the whole layer stack, one layer per
+            # vmapped packer call.
             packed, scales = jax.vmap(
-                lambda wl: cls._pack_pair(wl, fmt, "jnp", grouped=False))(w)
+                lambda wl: cls._pack_pair(wl, fmt, be, grouped=False))(w)
         else:
-            be = backend or default_backend()
             packed, scales = cls._pack_pair(w, fmt, be, grouped=False)
         return cls(packed=packed, k=k, n=n, plan=plan, scales=scales)
 
@@ -312,10 +315,10 @@ class GroupedPackedWeight(_PackedCommon):
         fmt = plan.b_format
         be = backend or default_backend()
         if w.ndim == 4:
-            # Load-time packing of the whole layer stack (jnp packer: runs
-            # once, identical buffer layout to the Pallas packer's).
+            # Load-time packing of the whole layer stack, one layer per
+            # vmapped packer call.
             packed, scales = jax.vmap(
-                lambda wl: cls._pack_pair(wl, fmt, "jnp", grouped=True))(w)
+                lambda wl: cls._pack_pair(wl, fmt, be, grouped=True))(w)
         else:
             packed, scales = cls._pack_pair(w, fmt, be, grouped=True)
         return cls(packed=packed, e=e, k=k, n=n, plan=plan, scales=scales)

@@ -8,8 +8,11 @@ tile multiples (mr, kr, nr) chosen from the matrix-engine geometry.
 On TPU the hierarchy collapses to a single software-managed VMEM with Pallas
 double-buffering the HBM streams, and the register tile becomes the MXU tile:
 
-  (C1)  working set fits VMEM:
-        dbuf*(bm*bk + bk*bn)*itemsize + bm*bn*acc_itemsize <= vmem_budget
+  (C1)  working set fits VMEM (every buffer the kernel asks Mosaic for):
+        dbuf*(bm*bk + bk*bn)*itemsize      double-buffered A and B streams
+          + bm*bn*acc_itemsize             the revolving accumulator
+          + dbuf*bm*bn*acc_itemsize        the output block (f32 at widest)
+          <= vmem_budget  (derived from the chip's declared VMEM limit)
   (C2)  MXU feeding geometry:  bm % sublane == 0, bn % lane == 0, bk % lane == 0
   (C3)  accumulator grid:      bm, bn multiples of the 128x128 MXU tile when
         possible (VAccs = bm/128, HAccs = bn/128 — paper Fig. 3 generalized)
@@ -29,7 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import dtypes as mdt
 from repro.core.tile_format import ScaleSpec, TileFormat, is_dequant_pair
-from repro.roofline.hw import V5E, TpuTarget
+from repro.roofline.hw import TpuTarget, current_target
 
 
 @dataclasses.dataclass(frozen=True)
@@ -42,7 +45,8 @@ class GemmPlan:
     layout_a: str = "row"
     layout_b: str = "row"
     double_buffer: int = 2
-    vmem_budget: int = V5E.vmem_bytes
+    vmem_budget: int = dataclasses.field(
+        default_factory=lambda: current_target().vmem_bytes)
     # B-operand element dtype when it differs from the compute dtype —
     # int8/int4 weight streams (dequant-in-epilogue) halve/quarter the
     # resident B footprint, so the byte accounting below is per-operand.
@@ -53,11 +57,11 @@ class GemmPlan:
 
     @property
     def vaccs(self) -> int:
-        return max(self.bm // V5E.mxu_dim, 1)
+        return max(self.bm // current_target().mxu_dim, 1)
 
     @property
     def haccs(self) -> int:
-        return max(self.bn // V5E.mxu_dim, 1)
+        return max(self.bn // current_target().mxu_dim, 1)
 
     @property
     def b_format(self) -> TileFormat:
@@ -78,10 +82,11 @@ class GemmPlan:
         # B streams at the tile format's bytes (narrow int8 B tiles carry a
         # per-tile scale — counted, though it is noise next to the tile).
         b_stream = self.double_buffer * self.b_format.tile_bytes()
-        return a_stream + b_stream + self.bm * self.bn * acc_item
+        acc_and_out = (1 + self.double_buffer) * self.bm * self.bn * acc_item
+        return a_stream + b_stream + acc_and_out
 
-    def validate(self, target: TpuTarget = V5E) -> None:
-        sub, lane = mdt.alignment(self.dtype, target)
+    def validate(self, target: TpuTarget | None = None) -> None:
+        sub, lane = mdt.alignment(self.dtype, target or current_target())
         if self.vmem_working_set() > self.vmem_budget:
             raise ValueError(
                 f"plan {self} exceeds VMEM budget: "
@@ -101,7 +106,7 @@ def _round_down(x: int, mult: int) -> int:
 
 def plan_gemm(m: int, k: int, n: int, dtype="float32", *,
               b_dtype: str | None = None,
-              target: TpuTarget = V5E,
+              target: TpuTarget | None = None,
               vmem_budget: int | None = None,
               double_buffer: int = 2,
               layout_a: str = "row",
@@ -117,6 +122,7 @@ def plan_gemm(m: int, k: int, n: int, dtype="float32", *,
     ``scale_granularity`` picks the quantized format's scale convention
     ("tile" per-(Kb,Nb), "col" per-Nb-column store-only dequant).
     """
+    target = target or current_target()
     d = mdt.info(jnp.dtype(dtype).name if not isinstance(dtype, str) else dtype)
     b_item = (mdt.info(jnp.dtype(b_dtype).name).itemsize if b_dtype
               else d.itemsize)
@@ -141,8 +147,11 @@ def plan_gemm(m: int, k: int, n: int, dtype="float32", *,
     bn = clipped(4 * mxu, n, lane)
 
     # (C1) maximize bk first — the paper's "larger kc" insight (Eq. 1).
+    def acc_and_out(bm_: int, bn_: int) -> int:
+        return (1 + double_buffer) * bm_ * bn_ * acc_item
+
     def max_bk(bm_: int, bn_: int) -> int:
-        avail = budget - bm_ * bn_ * acc_item - scale_bytes
+        avail = budget - acc_and_out(bm_, bn_) - scale_bytes
         # per_k may be fractional (sub-byte b_item): floor to int k-steps.
         per_k = double_buffer * (bm_ * d.itemsize + bn_ * b_item)
         return max(int(avail / per_k), lane)
@@ -153,7 +162,7 @@ def plan_gemm(m: int, k: int, n: int, dtype="float32", *,
     # re-checking the budget after each growth step.
     def fits(bm_, bk_, bn_):
         ws = (double_buffer * (bm_ * bk_ * d.itemsize + bk_ * bn_ * b_item)
-              + bm_ * bn_ * acc_item + scale_bytes)
+              + acc_and_out(bm_, bn_) + scale_bytes)
         return ws <= budget
 
     for cand in (8 * mxu, 4 * mxu, 2 * mxu):
@@ -179,6 +188,13 @@ def plan_gemm(m: int, k: int, n: int, dtype="float32", *,
     while not fits(bm, bk, bn) and bm > sub:
         bm = _round_down(bm // 2, sub)
 
+    # Balance the K split: a budget-capped bk that does not divide K would
+    # zero-fill most of a last K tile (up to ~2x the B stream); the same
+    # number of K steps at the shallowest lane-aligned depth covers K with
+    # under one lane of fill. Never deeper than before, so (C1) still holds.
+    k_steps = -(-k // bk)
+    bk = -(-k // (k_steps * lane)) * lane
+
     plan = GemmPlan(bm=bm, bk=bk, bn=bn, dtype=d.name, acc_dtype=d.acc_dtype,
                     layout_a=layout_a, layout_b=layout_b,
                     double_buffer=double_buffer, vmem_budget=budget,
@@ -189,7 +205,7 @@ def plan_gemm(m: int, k: int, n: int, dtype="float32", *,
 
 def plan_grouped_gemm(e: int, m: int, k: int, n: int, dtype="float32", *,
                       b_dtype: str | None = None,
-                      target: TpuTarget = V5E,
+                      target: TpuTarget | None = None,
                       n_b_streams: int = 1,
                       double_buffer: int = 2,
                       layout_b: str = "row",
@@ -204,6 +220,7 @@ def plan_grouped_gemm(e: int, m: int, k: int, n: int, dtype="float32", *,
     revolving accumulator share VMEM with the first). The budget is solved
     with that reservation subtracted, then re-validated.
     """
+    target = target or current_target()
     d = mdt.info(jnp.dtype(dtype).name if not isinstance(dtype, str) else dtype)
     acc_item = jnp.dtype(d.acc_dtype).itemsize
 
@@ -234,7 +251,7 @@ def plan_grouped_gemm(e: int, m: int, k: int, n: int, dtype="float32", *,
 
 def should_pack(m: int, k: int, n: int, dtype="float32", *,
                 b_dtype: str | None = None,
-                target: TpuTarget = V5E, fused: bool = False,
+                target: TpuTarget | None = None, fused: bool = False,
                 group: int = 1, occupancy: float = 1.0) -> bool:
     """Strategy heuristic from the paper's own results: packing pays off once
     operands exceed the fast-memory envelope (Figs. 4-6: Tiling wins small,
@@ -270,6 +287,7 @@ def should_pack(m: int, k: int, n: int, dtype="float32", *,
     dispatch whose padded capacity looks prefill-shaped but whose occupied
     rows fit a sublane block makes the einsum call, not the kernel call.
     """
+    target = target or current_target()
     item = mdt.info(jnp.dtype(dtype).name if not isinstance(dtype, str)
                     else dtype).itemsize
     # B's resident/streamed bytes are counted at B's OWN dtype: an int8
@@ -290,7 +308,7 @@ def should_pack(m: int, k: int, n: int, dtype="float32", *,
 
 def choose_grouped_strategy(e: int, m: int, k: int, n: int, dtype="float32",
                             *, b_dtype: str | None = None,
-                            target: TpuTarget = V5E,
+                            target: TpuTarget | None = None,
                             counts_known: bool = False,
                             occupancy: float = 1.0) -> str:
     """Grouped analogue of :func:`choose_strategy` — the planner's cost model
@@ -312,7 +330,7 @@ def choose_grouped_strategy(e: int, m: int, k: int, n: int, dtype="float32",
 
 def choose_strategy(m: int, k: int, n: int, dtype="float32", *,
                     b_dtype: str | None = None,
-                    target: TpuTarget = V5E,
+                    target: TpuTarget | None = None,
                     weights_prepacked: bool = False) -> str:
     """Pick the kernel strategy for a problem signature.
 

@@ -43,12 +43,14 @@ stack. Two granularities are defined:
     error envelope than per-tile scales).
 
 SUB-BYTE formats: ``dtype="int4"`` stores TWO values per byte — nibble-packed
-along the trailing (minor) tile axis, element ``2i`` in the LOW nibble and
-``2i+1`` in the HIGH nibble of stored byte ``i`` (see :func:`pack_nibbles`).
-The physical buffer dtype is int8 with the trailing tile dim halved
-(``storage_tile_shape``); kernels widen the VMEM tile back to i8 via
-shift/mask (:func:`unpack_nibbles`) inside the tile load, so HBM→VMEM B
-traffic is 0.25x bf16. Quantized int4 values live in [-7, 7]
+along the trailing (minor) tile axis of length ``t``, element ``i`` in the
+LOW nibble and element ``i + t/2`` in the HIGH nibble of stored byte ``i``
+(see :func:`pack_nibbles`). The physical buffer dtype is int8 with the
+trailing tile dim halved (``storage_tile_shape``); kernels widen the VMEM
+tile back to i8 via shift/mask (:func:`unpack_nibbles`) inside the tile
+load — the two halves are lane-contiguous, so the widen is a concatenation
+the TPU compiler lowers, not a lane interleave — and HBM→VMEM B traffic is
+0.25x bf16. Quantized int4 values live in [-7, 7]
 (``scale = absmax/7``).
 
 Both descriptors are frozen/hashable — safe as pytree-static aux data, jit
@@ -70,32 +72,34 @@ def cdiv(a: int, b: int) -> int:
 def pack_nibbles(q: jnp.ndarray) -> jnp.ndarray:
     """Nibble-pack an int stack along its trailing axis (two values/byte).
 
-    Element ``2i`` lands in the LOW nibble and ``2i+1`` in the HIGH nibble of
-    output byte ``i`` — THE sub-byte storage convention of ``dtype="int4"``
-    formats. Values must fit in [-8, 7]; the trailing dim must be even (the
-    pack layer's zero-fill envelope guarantees this for ragged K/N edges).
+    Of a trailing axis of length ``t``, element ``i`` lands in the LOW
+    nibble and element ``i + t/2`` in the HIGH nibble of output byte ``i`` —
+    THE sub-byte storage convention of ``dtype="int4"`` formats. Values must
+    fit in [-8, 7]; the trailing dim must be even (the pack layer's
+    zero-fill envelope guarantees this for ragged K/N edges).
     """
     if q.shape[-1] % 2:
         raise ValueError(f"nibble pack needs an even trailing dim, "
                          f"got {q.shape}")
     q = q.astype(jnp.int8)
-    lo, hi = q[..., 0::2], q[..., 1::2]
+    half = q.shape[-1] // 2
+    lo, hi = q[..., :half], q[..., half:]
     return ((lo & 0xF) | ((hi & 0xF) << 4)).astype(jnp.int8)
 
 
 def unpack_nibbles(p: jnp.ndarray) -> jnp.ndarray:
     """Invert :func:`pack_nibbles`: int8 nibble-pairs -> sign-extended i8.
 
-    Pure shift/mask arithmetic (``(x << 4) >> 4`` sign-extends the low
-    nibble; ``x >> 4`` is arithmetic on int8), so it runs unchanged on a
-    VMEM tile inside a kernel body — the in-register widen of the sub-byte
-    tile load. Output trailing dim is 2x the input's.
+    Pure shift/mask arithmetic on 32-bit lanes (``(x << 28) >> 28``
+    sign-extends the low nibble, ``(x << 24) >> 28`` the high one — the TPU
+    vector unit has no 8-bit shifts), so it runs unchanged on a VMEM tile
+    inside a kernel body — the in-register widen of the sub-byte tile load.
+    Output trailing dim is 2x the input's: the low nibbles, then the high.
     """
-    p = p.astype(jnp.int8)
-    lo = jnp.left_shift(p, 4) >> 4
-    hi = p >> 4
-    return jnp.stack([lo, hi], axis=-1).reshape(
-        *p.shape[:-1], p.shape[-1] * 2)
+    p = p.astype(jnp.int32)
+    lo = jnp.left_shift(p, 28) >> 28
+    hi = jnp.left_shift(p, 24) >> 28
+    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
 
 
 @dataclasses.dataclass(frozen=True)

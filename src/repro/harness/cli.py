@@ -24,6 +24,7 @@ import sys
 import time
 from typing import Optional
 
+from repro.compile_cache import enable_compile_cache
 from repro.harness import baselines as bl
 from repro.harness import registry
 from repro.harness.runner import run_plan
@@ -103,6 +104,7 @@ def main(argv=None, *, package: str = "benchmarks",
     run_dir = (pathlib.Path(args.run_dir) if args.run_dir
                else root / "results" / "harness" / run_id)
 
+    enable_compile_cache()
     report = run_plan(
         plan, root=root, run_dir=run_dir, run_id=run_id, check=args.check,
         committed_baselines=committed,

@@ -17,8 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import cdiv, default_interpret, pallas_kwargs, vmem_scratch
+from repro.kernels.common import cdiv, default_interpret, pallas_kwargs
 
 _NEG_INF = -1e30  # finite sentinel: avoids (-inf) - (-inf) NaNs in rescaling
 
@@ -115,9 +116,9 @@ def flash_attention(q: jnp.ndarray,
                                lambda bh, i, j: (bh // h, i, bh % h, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq + pq, h, d), q.dtype),
         scratch_shapes=[
-            vmem_scratch((bq_,), jnp.float32),
-            vmem_scratch((bq_,), jnp.float32),
-            vmem_scratch((bq_, d), jnp.float32),
+            pltpu.VMEM((bq_,), jnp.float32),
+            pltpu.VMEM((bq_,), jnp.float32),
+            pltpu.VMEM((bq_, d), jnp.float32),
         ],
         **pallas_kwargs(
             interpret=interpret,

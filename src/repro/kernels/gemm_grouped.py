@@ -53,27 +53,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.tile_format import TileFormat
 from repro.kernels.common import (KERNEL_EPILOGUES, GemmRefs, acc_dtype_for,
-                                  b_tile_spec, cdiv, contract_tile,
-                                  default_interpret, pad2d, pallas_kwargs,
-                                  scale_tile_spec, tpu_compiler_params,
-                                  vmem_scratch)
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+                                  b_tile_spec, cdiv, col_scaled,
+                                  contract_tile, default_interpret, pad2d,
+                                  pallas_kwargs, scale_operand,
+                                  scale_spec, tile_scale)
 
 
-def _grouped_kernel(*refs, k_steps, fmt, epilogue, has_bias, has_scale,
-                    has_gate):
+def _grouped_scales(r: GemmRefs, fmt: TileFormat, **coords):
+    """(gate scale, up scale) of the current B tile(s) from the SMEM grids
+    (None when unquantized)."""
+    if r.scale is None:
+        return None, None
+    s2 = (tile_scale(r.scale2, fmt, **coords) if r.scale2 is not None
+          else None)
+    return tile_scale(r.scale, fmt, **coords), s2
+
+
+def _grouped_store(r: GemmRefs, fmt: TileFormat, epilogue: str, scales):
+    """The grouped kernels' fused store epilogue on the VMEM accumulators:
+    (hoisted col-scale dequant,) bias, then activation or silu-gate."""
+    col = col_scaled(fmt)
+    out = r.acc[...]
+    if col and scales[0] is not None:  # hoisted dequant, ahead of the rest
+        out = out * scales[0].astype(out.dtype)
+    if r.bias is not None:
+        out = out + r.bias[0].astype(out.dtype)     # [1,bn] broadcast
+    if r.acc2 is None:
+        return KERNEL_EPILOGUES[epilogue](out)
+    # silu(gate) * up on the VMEM accumulators — the MoE pair fusion.
+    up = r.acc2[...]
+    if col and scales[1] is not None:
+        up = up * scales[1].astype(up.dtype)
+    return KERNEL_EPILOGUES["silu"](out) * up
+
+
+def _grouped_kernel(*refs, k_steps, n_blocks, fmt, epilogue, has_bias,
+                    has_scale, has_gate):
     r = GemmRefs(refs, n_lead=2, has_gate=has_gate, has_scale=has_scale,
                  has_bias=has_bias)
     a_ref, b_ref = r.lead
+    kk = pl.program_id(3)
 
-    @pl.when(pl.program_id(3) == 0)
+    @pl.when(kk == 0)
     def _init():
         r.acc[...] = jnp.zeros_like(r.acc)
         if has_gate:
@@ -82,31 +107,34 @@ def _grouped_kernel(*refs, k_steps, fmt, epilogue, has_bias, has_scale,
     a = a_ref[0]       # [bm, bk] strided block of the NATURAL [E, M, K] layout
     # Quantized stacks dequantize per K-step (per-tile scale on the f32
     # accumulator path, gate and up each with their own scale grid).
-    # Col-granularity scales are K-invariant: contract_tile skips them and
-    # the epilogue below applies them once (store-only dequant).
-    r.acc[...] += contract_tile(a, b_ref[0, 0, 0], r.scale, fmt, r.acc.dtype)
+    # Col-granularity scales are K-invariant: the store epilogue applies
+    # them once (store-only dequant).
+    scales = _grouped_scales(r, fmt, nb=n_blocks, kb=k_steps,
+                             e=pl.program_id(0), j=pl.program_id(2), kk=kk)
+    step = (None, None) if col_scaled(fmt) else scales
+    r.acc[...] += contract_tile(a, b_ref[0, 0, 0], step[0], fmt, r.acc.dtype)
     if has_gate:
-        r.acc2[...] += contract_tile(a, r.b2[0, 0, 0], r.scale2, fmt,
+        r.acc2[...] += contract_tile(a, r.b2[0, 0, 0], step[1], fmt,
                                      r.acc2.dtype)
 
-    col_scale = fmt.scale is not None and fmt.scale.granularity == "col"
-
-    @pl.when(pl.program_id(3) == k_steps - 1)
+    @pl.when(kk == k_steps - 1)
     def _epilogue():
-        out = r.acc[...]
-        if col_scale:  # hoisted dequant, ahead of bias/activation/gate
-            out = out * r.scale[...].reshape(1, 1).astype(out.dtype)
-        if r.bias is not None:
-            out = out + r.bias[0].astype(out.dtype)     # [1,bn] broadcast
-        if has_gate:
-            # silu(gate) * up on the VMEM accumulators — the MoE pair fusion.
-            up = r.acc2[...]
-            if col_scale:
-                up = up * r.scale2[...].reshape(1, 1).astype(up.dtype)
-            out = KERNEL_EPILOGUES["silu"](out) * up
-        else:
-            out = KERNEL_EPILOGUES[epilogue](out)
-        r.out[0] = out.astype(r.out.dtype)
+        r.out[0] = _grouped_store(r, fmt, epilogue, scales).astype(r.out.dtype)
+
+
+def _append_scales(in_specs, operands, fmt, grid_enk, b_shape, b_scales,
+                   b2_scales):
+    """Append the gate (and up) scale grids as flat SMEM operands."""
+    if b_scales is None:
+        return
+    e, nb, kb = grid_enk
+    want = (e, nb) if col_scaled(fmt) else (e, nb, kb)
+    for sc in (b_scales, b2_scales):
+        if sc is None:
+            continue
+        assert sc.shape == want, (sc.shape, b_shape, want)
+        in_specs.append(scale_spec())
+        operands.append(scale_operand(sc))
 
 
 def gemm_grouped_packed(a: jnp.ndarray,
@@ -179,17 +207,8 @@ def gemm_grouped_packed(a: jnp.ndarray,
     if has_gate:
         in_specs.append(b_tile_spec(fmt, b_map, lead=3))
         operands.append(b2_packed)
-    if has_scale:
-        col = fmt.scale is not None and fmt.scale.granularity == "col"
-        want = (e, nb) if col else (e, nb, kb)
-        assert b_scales.shape == want, (b_scales.shape, b_packed.shape, want)
-        in_specs.append(scale_tile_spec(fmt, b_map, lead=3))
-        operands.append(b_scales)
-        if has_gate:
-            assert b2_scales.shape == want, (b2_scales.shape,
-                                             b_packed.shape, want)
-            in_specs.append(scale_tile_spec(fmt, b_map, lead=3))
-            operands.append(b2_scales)
+    _append_scales(in_specs, operands, fmt, (e, nb, kb), b_packed.shape,
+                   b_scales, b2_scales if has_gate else None)
     has_bias = bias is not None
     if has_bias:
         assert bias.shape == (e, n), (bias.shape, (e, n))
@@ -197,12 +216,12 @@ def gemm_grouped_packed(a: jnp.ndarray,
             pl.BlockSpec((1, 1, bn), lambda ee, i, j, kk: (ee, 0, j)))
         operands.append(jax.vmap(
             lambda be: pad2d(be.reshape(1, n), 1, bn))(bias))
-    scratch = [vmem_scratch((bm, bn), acc_dtype)]
+    scratch = [pltpu.VMEM((bm, bn), acc_dtype)]
     if has_gate:
-        scratch.append(vmem_scratch((bm, bn), acc_dtype))
+        scratch.append(pltpu.VMEM((bm, bn), acc_dtype))
 
     out = pl.pallas_call(
-        functools.partial(_grouped_kernel, k_steps=kb, fmt=fmt,
+        functools.partial(_grouped_kernel, k_steps=kb, n_blocks=nb, fmt=fmt,
                           epilogue=epilogue, has_bias=has_bias,
                           has_scale=has_scale, has_gate=has_gate),
         grid=grid,
@@ -222,21 +241,25 @@ def gemm_grouped_packed(a: jnp.ndarray,
 # Ragged (occupancy-aware) grouped GEMM
 # ---------------------------------------------------------------------------
 
-def _ragged_kernel(*refs, k_steps, bm, fmt, epilogue, has_bias, has_scale,
-                   has_gate):
+def _ragged_kernel(*refs, k_steps, n_blocks, segments, bm, fmt, epilogue,
+                   has_bias, has_scale, has_gate):
     r = GemmRefs(refs, n_lead=3, has_gate=has_gate, has_scale=has_scale,
                  has_bias=has_bias)
     counts_ref, a_ref, b_ref = r.lead
 
     g = pl.program_id(0)
     i = pl.program_id(1)
+    kk = pl.program_id(3)
     # Valid rows of THIS m-block: whole blocks below the count contribute bm,
     # the partial block gets the remainder, blocks past the count get 0.
     bc = jnp.clip(counts_ref[g] - i * bm, 0, bm)
     live = bc > 0
-    last_k = pl.program_id(3) == k_steps - 1
+    last_k = kk == k_steps - 1
+    scales = _grouped_scales(r, fmt, nb=n_blocks, kb=k_steps,
+                             e=g // segments, j=pl.program_id(2), kk=kk)
+    step = (None, None) if col_scaled(fmt) else scales
 
-    @pl.when(live & (pl.program_id(3) == 0))
+    @pl.when(live & (kk == 0))
     def _init():
         r.acc[...] = jnp.zeros_like(r.acc)
         if has_gate:
@@ -246,28 +269,15 @@ def _ragged_kernel(*refs, k_steps, bm, fmt, epilogue, has_bias, has_scale,
     # the grid still visits the step, but the MXU never fires.
     @pl.when(live)
     def _acc():
-        r.acc[...] += contract_tile(a_ref[0], b_ref[0, 0, 0], r.scale, fmt,
+        r.acc[...] += contract_tile(a_ref[0], b_ref[0, 0, 0], step[0], fmt,
                                     r.acc.dtype)
         if has_gate:
-            r.acc2[...] += contract_tile(a_ref[0], r.b2[0, 0, 0], r.scale2,
+            r.acc2[...] += contract_tile(a_ref[0], r.b2[0, 0, 0], step[1],
                                          fmt, r.acc2.dtype)
-
-    col_scale = fmt.scale is not None and fmt.scale.granularity == "col"
 
     @pl.when(live & last_k)
     def _epilogue():
-        out = r.acc[...]
-        if col_scale:  # hoisted dequant, ahead of bias/activation/gate
-            out = out * r.scale[...].reshape(1, 1).astype(out.dtype)
-        if r.bias is not None:
-            out = out + r.bias[0].astype(out.dtype)
-        if has_gate:
-            up = r.acc2[...]
-            if col_scale:
-                up = up * r.scale2[...].reshape(1, 1).astype(up.dtype)
-            out = KERNEL_EPILOGUES["silu"](out) * up
-        else:
-            out = KERNEL_EPILOGUES[epilogue](out)
+        out = _grouped_store(r, fmt, epilogue, scales)
         # Masked store: rows at/past the count are written as zeros, so
         # dropped-token slots never carry garbage (or a bias image) to HBM.
         rows = jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
@@ -307,9 +317,8 @@ def gemm_grouped_packed_ragged(a: jnp.ndarray,
     b_packed: [E, Nb, Kb, bk, bn] from ``pack.pack_b_grouped`` (load time).
     b_scales / b2_scales: f32 scale grids (quantized stacks): per-tile
               [E, Nb, Kb] or per-column [E, Nb] (``granularity="col"``,
-              dequant hoisted into the store epilogue). The scale operand's
-              index map mirrors B's — including the count-aware index
-              pinning, so skipped steps fetch no new scales either.
+              dequant hoisted into the store epilogue), resident in SMEM
+              and read at the B tile coordinates of live steps only.
     b_format: authoritative :class:`TileFormat` (REQUIRED for int4 /
               col-scale stacks; inferred from the buffer when omitted).
 
@@ -317,10 +326,6 @@ def gemm_grouped_packed_ragged(a: jnp.ndarray,
     masked tail rows, the result is identical to ``gemm_grouped_packed`` on
     the same operands with the padding rows zeroed.
     """
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("gemm_grouped_packed_ragged needs "
-                           "jax.experimental.pallas.tpu "
-                           "(PrefetchScalarGridSpec)")
     if interpret is None:
         interpret = default_interpret()
     has_gate = epilogue == "silu_gate"
@@ -374,17 +379,8 @@ def gemm_grouped_packed_ragged(a: jnp.ndarray,
     if has_gate:
         in_specs.append(b_tile_spec(fmt, b_map, lead=3))
         operands.append(b2_packed)
-    if has_scale:
-        col = fmt.scale is not None and fmt.scale.granularity == "col"
-        want = (e, nb) if col else (e, nb, kb)
-        assert b_scales.shape == want, (b_scales.shape, b_packed.shape, want)
-        in_specs.append(scale_tile_spec(fmt, b_map, lead=3))
-        operands.append(b_scales)
-        if has_gate:
-            assert b2_scales.shape == want, (b2_scales.shape,
-                                             b_packed.shape, want)
-            in_specs.append(scale_tile_spec(fmt, b_map, lead=3))
-            operands.append(b2_scales)
+    _append_scales(in_specs, operands, fmt, (e, nb, kb), b_packed.shape,
+                   b_scales, b2_scales if has_gate else None)
     has_bias = bias is not None
     if has_bias:
         assert bias.shape == (e, n), (bias.shape, (e, n))
@@ -392,9 +388,9 @@ def gemm_grouped_packed_ragged(a: jnp.ndarray,
             pl.BlockSpec((1, 1, bn), lambda g, i, j, kk, cnt: (g // s, 0, j)))
         operands.append(jax.vmap(
             lambda be: pad2d(be.reshape(1, n), 1, bn))(bias))
-    scratch = [vmem_scratch((bm, bn), acc_dtype)]
+    scratch = [pltpu.VMEM((bm, bn), acc_dtype)]
     if has_gate:
-        scratch.append(vmem_scratch((bm, bn), acc_dtype))
+        scratch.append(pltpu.VMEM((bm, bn), acc_dtype))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -404,19 +400,17 @@ def gemm_grouped_packed_ragged(a: jnp.ndarray,
                                lambda g, i, j, kk, cnt: (g, i, j)),
         scratch_shapes=scratch,
     )
-    kwargs = {"interpret": interpret}
-    if not interpret:
-        params = tpu_compiler_params(
-            ("parallel", "parallel", "parallel", "arbitrary"))
-        if params is not None:
-            kwargs["compiler_params"] = params
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, k_steps=kb, bm=bm, fmt=fmt,
-                          epilogue=epilogue, has_bias=has_bias,
-                          has_scale=has_scale, has_gate=has_gate),
+        functools.partial(_ragged_kernel, k_steps=kb, n_blocks=nb,
+                          segments=s, bm=bm, fmt=fmt, epilogue=epilogue,
+                          has_bias=has_bias, has_scale=has_scale,
+                          has_gate=has_gate),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((grp, mb * bm, nb * bn), out_dtype),
-        **kwargs,
+        **pallas_kwargs(
+            interpret=interpret,
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
     )(counts_flat, *operands)
     return out[:, :c, :n].reshape(e, s, c, n)
 

@@ -32,18 +32,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.tile_format import TileFormat
-from repro.kernels.common import (acc_dtype_for, b_tile_spec,
-                                  bias_spec_and_operand, cdiv, contract_tile,
+from repro.kernels.common import (GemmRefs, acc_dtype_for, b_tile_spec,
+                                  bias_spec_and_operand, c_spec_and_operand,
+                                  cdiv, col_scaled, contract_tile,
                                   default_interpret, finalize_gemm, pad2d,
-                                  pallas_kwargs, scale_tile_spec,
-                                  split_epilogue_refs, vmem_scratch)
+                                  pallas_kwargs, scale_operand, scale_spec,
+                                  tile_scale)
 
 
-def _packed_kernel(a_ref, b_ref, c_ref, *rest, alpha, beta, k_steps,
-                   layout_a, fmt, epilogue="none", has_bias=False):
-    _, bias_ref, o_ref, acc_ref = split_epilogue_refs(rest, has_bias)
+def _packed_kernel(*refs, alpha, beta, k_steps, layout_a, fmt,
+                   epilogue="none", has_c=False, has_bias=False):
+    r = GemmRefs(refs, n_lead=2, has_c=has_c, has_bias=has_bias)
+    a_ref, b_ref = r.lead
+    acc_ref = r.acc
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -59,35 +63,40 @@ def _packed_kernel(a_ref, b_ref, c_ref, *rest, alpha, beta, k_steps,
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
-        finalize_gemm(acc_ref, c_ref, bias_ref, o_ref, alpha=alpha, beta=beta,
+        finalize_gemm(acc_ref, r.c, r.bias, r.out, alpha=alpha, beta=beta,
                       epilogue=epilogue)
 
 
-def _fused_a_kernel(a_ref, b_ref, c_ref, *rest, alpha, beta, k_steps,
-                    fmt, epilogue="none", has_bias=False, has_scale=False):
-    scale_ref, bias_ref, o_ref, acc_ref = split_epilogue_refs(
-        rest, has_bias, has_scale)
+def _fused_a_kernel(*refs, alpha, beta, k_steps, n_blocks, fmt,
+                    epilogue="none", has_c=False, has_bias=False,
+                    has_scale=False):
+    r = GemmRefs(refs, n_lead=2, has_c=has_c, has_scale=has_scale,
+                 has_bias=has_bias)
+    a_ref, b_ref = r.lead
+    acc_ref = r.acc
+    j, kk = pl.program_id(1), pl.program_id(2)
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(kk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     a = a_ref[...]   # [bm,bk] strided block of the NATURAL [M,K] operand
     b = b_ref[0, 0]  # [bk,bn] ("row") or [bn,bk] ("col") pre-packed tile
     # Quantized B dequantizes per K-step on the f32 accumulator (the tile's
-    # scalar scale rides the mirrored BlockSpec), ahead of the store
-    # epilogue. A col-granularity scale is K-invariant and hoists out of
-    # the K loop entirely: contract_tile skips it and finalize_gemm applies
-    # it once to the finished accumulator (store-only dequant).
-    acc_ref[...] += contract_tile(a, b, scale_ref, fmt, acc_ref.dtype)
+    # scalar scale is read from the SMEM grid at B's tile coordinates),
+    # ahead of the store epilogue. A col-granularity scale is K-invariant
+    # and hoists out of the K loop entirely: finalize_gemm applies it once
+    # to the finished accumulator (store-only dequant).
+    scale = (tile_scale(r.scale, fmt, nb=n_blocks, kb=k_steps, j=j, kk=kk)
+             if has_scale else None)
+    col = col_scaled(fmt)
+    acc_ref[...] += contract_tile(a, b, None if col else scale, fmt,
+                                  acc_ref.dtype)
 
-    col_scale = fmt.scale is not None and fmt.scale.granularity == "col"
-
-    @pl.when(pl.program_id(2) == k_steps - 1)
+    @pl.when(kk == k_steps - 1)
     def _epilogue():
-        finalize_gemm(acc_ref, c_ref, bias_ref, o_ref, alpha=alpha, beta=beta,
-                      epilogue=epilogue,
-                      scale_ref=scale_ref if col_scale else None)
+        finalize_gemm(acc_ref, r.c, r.bias, r.out, alpha=alpha, beta=beta,
+                      epilogue=epilogue, scale=scale if col else None)
 
 
 def gemm_packed(a_packed: jnp.ndarray,
@@ -123,21 +132,19 @@ def gemm_packed(a_packed: jnp.ndarray,
     assert bk == fmt.bk, (a_packed.shape, b_packed.shape)
     out_dtype = out_dtype or (c.dtype if c is not None else a_packed.dtype)
     acc_dtype = acc_dtype_for(a_packed.dtype)
-    if c is None:
-        beta = 0
-        c_p = jnp.zeros((mb * bm, nb * bn), out_dtype)
-    else:
-        assert c.shape == (m, n)
-        c_p = pad2d(c, bm, bn)
 
     grid = (mb, nb, kb)  # K innermost: revolving accumulator, one HBM store
     ta = a_packed.shape[2:]
     in_specs = [
         pl.BlockSpec((1, 1) + ta, lambda i, j, kk: (i, kk, 0, 0)),
         b_tile_spec(fmt, lambda i, j, kk: (j, kk, 0, 0)),
-        pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
     ]
-    operands = [a_packed, b_packed, c_p]
+    operands = [a_packed, b_packed]
+    has_c = c is not None
+    if has_c:
+        spec, op = c_spec_and_operand(c, m, n, bm, bn)
+        in_specs.append(spec)
+        operands.append(op)
     has_bias = bias is not None
     if has_bias:
         spec, op = bias_spec_and_operand(bias, n, bn)
@@ -145,13 +152,13 @@ def gemm_packed(a_packed: jnp.ndarray,
         operands.append(op)
     out = pl.pallas_call(
         functools.partial(_packed_kernel, alpha=alpha, beta=beta, k_steps=kb,
-                          layout_a=layout_a, fmt=fmt,
-                          epilogue=epilogue, has_bias=has_bias),
+                          layout_a=layout_a, fmt=fmt, epilogue=epilogue,
+                          has_c=has_c, has_bias=has_bias),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mb * bm, nb * bn), out_dtype),
-        scratch_shapes=[vmem_scratch((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         **pallas_kwargs(
             interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -182,9 +189,9 @@ def gemm_packed_fused_a(a: jnp.ndarray,
     ``pack_b`` (typically once, at weight-load time).
 
     ``b_scales`` (f32, from a quantized ``pack_b``) marks B as
-    dequant-in-epilogue: [Nb, Kb] per-tile scales ride a BlockSpec
-    mirroring B's index map and multiply each K-step's partial product on
-    the f32 accumulator; [Nb] per-column scales (``granularity="col"``)
+    dequant-in-epilogue: [Nb, Kb] per-tile scales sit whole in SMEM, are
+    read at B's tile coordinates and multiply each K-step's partial product
+    on the f32 accumulator; [Nb] per-column scales (``granularity="col"``)
     multiply the finished accumulator once in the store epilogue, ahead of
     bias/activation. ``b_format`` is the authoritative :class:`TileFormat`
     of the packed stack — REQUIRED for nibble-packed int4 buffers (an int4
@@ -204,28 +211,24 @@ def gemm_packed_fused_a(a: jnp.ndarray,
     acc_dtype = acc_dtype_for(a.dtype)
     a_p = pad2d(a, bm, bk)
     mb = cdiv(m, bm)
-    if c is None:
-        beta = 0
-        c_p = jnp.zeros((mb * bm, nb * bn), out_dtype)
-    else:
-        assert c.shape == (m, n)
-        c_p = pad2d(c, bm, bn)
 
     grid = (mb, nb, kb)
-    b_map = lambda i, j, kk: (j, kk, 0, 0)
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-        b_tile_spec(fmt, b_map),
-        pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+        b_tile_spec(fmt, lambda i, j, kk: (j, kk, 0, 0)),
     ]
-    operands = [a_p, b_packed, c_p]
+    operands = [a_p, b_packed]
+    has_c = c is not None
+    if has_c:
+        spec, op = c_spec_and_operand(c, m, n, bm, bn)
+        in_specs.append(spec)
+        operands.append(op)
     has_scale = b_scales is not None
     if has_scale:
-        col = fmt.scale is not None and fmt.scale.granularity == "col"
-        want = (nb,) if col else (nb, kb)
+        want = (nb,) if col_scaled(fmt) else (nb, kb)
         assert b_scales.shape == want, (b_scales.shape, b_packed.shape, want)
-        in_specs.append(scale_tile_spec(fmt, b_map))
-        operands.append(b_scales)
+        in_specs.append(scale_spec())
+        operands.append(scale_operand(b_scales))
     has_bias = bias is not None
     if has_bias:
         spec, op = bias_spec_and_operand(bias, n, bn)
@@ -233,13 +236,13 @@ def gemm_packed_fused_a(a: jnp.ndarray,
         operands.append(op)
     out = pl.pallas_call(
         functools.partial(_fused_a_kernel, alpha=alpha, beta=beta, k_steps=kb,
-                          fmt=fmt, epilogue=epilogue,
+                          n_blocks=nb, fmt=fmt, epilogue=epilogue, has_c=has_c,
                           has_bias=has_bias, has_scale=has_scale),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mb * bm, nb * bn), out_dtype),
-        scratch_shapes=[vmem_scratch((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         **pallas_kwargs(
             interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -25,19 +25,21 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (KERNEL_EPILOGUES, acc_dtype_for,
-                                  bias_spec_and_operand, cdiv,
-                                  default_interpret, finalize_gemm, pad2d,
-                                  pallas_kwargs, split_epilogue_refs,
-                                  vmem_scratch)
+from repro.kernels.common import (KERNEL_EPILOGUES, GemmRefs, acc_dtype_for,
+                                  bias_spec_and_operand, c_spec_and_operand,
+                                  cdiv, default_interpret, finalize_gemm,
+                                  pad2d, pallas_kwargs)
 
 _EPILOGUES = KERNEL_EPILOGUES  # back-compat alias (tests import this name)
 
 
-def _gemm_kernel(a_ref, b_ref, c_ref, *rest, alpha, beta, k_steps,
-                 epilogue="none", has_bias=False):
-    _, bias_ref, o_ref, acc_ref = split_epilogue_refs(rest, has_bias)
+def _gemm_kernel(*refs, alpha, beta, k_steps, epilogue="none", has_c=False,
+                 has_bias=False):
+    r = GemmRefs(refs, n_lead=2, has_c=has_c, has_bias=has_bias)
+    a_ref, b_ref = r.lead
+    acc_ref = r.acc
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -51,7 +53,7 @@ def _gemm_kernel(a_ref, b_ref, c_ref, *rest, alpha, beta, k_steps,
 
     @pl.when(pl.program_id(2) == k_steps - 1)
     def _epilogue():
-        finalize_gemm(acc_ref, c_ref, bias_ref, o_ref, alpha=alpha, beta=beta,
+        finalize_gemm(acc_ref, r.c, r.bias, r.out, alpha=alpha, beta=beta,
                       epilogue=epilogue)
 
 
@@ -76,12 +78,6 @@ def gemm_tiled(a: jnp.ndarray,
     assert k == k2, (a.shape, b.shape)
     out_dtype = out_dtype or (c.dtype if c is not None else a.dtype)
     acc_dtype = acc_dtype_for(a.dtype)
-    if c is None:
-        beta = 0
-        c_p = jnp.zeros((cdiv(m, bm) * bm, cdiv(n, bn) * bn), out_dtype)
-    else:
-        assert c.shape == (m, n)
-        c_p = pad2d(c, bm, bn)
     a_p = pad2d(a, bm, bk)
     b_p = pad2d(b, bk, bn)
     mb, kb, nb = cdiv(m, bm), cdiv(k, bk), cdiv(n, bn)
@@ -90,9 +86,13 @@ def gemm_tiled(a: jnp.ndarray,
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
     ]
-    operands = [a_p, b_p, c_p]
+    operands = [a_p, b_p]
+    has_c = c is not None
+    if has_c:
+        spec, op = c_spec_and_operand(c, m, n, bm, bn)
+        in_specs.append(spec)
+        operands.append(op)
     has_bias = bias is not None
     if has_bias:
         spec, op = bias_spec_and_operand(bias, n, bn)
@@ -101,12 +101,13 @@ def gemm_tiled(a: jnp.ndarray,
 
     out = pl.pallas_call(
         functools.partial(_gemm_kernel, alpha=alpha, beta=beta, k_steps=kb,
-                          epilogue=epilogue, has_bias=has_bias),
+                          epilogue=epilogue, has_c=has_c,
+                          has_bias=has_bias),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mb * bm, nb * bn), out_dtype),
-        scratch_shapes=[vmem_scratch((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         **pallas_kwargs(
             interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -19,11 +19,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.tile_format import TileFormat
 from repro.kernels.common import (acc_dtype_for, b_tile_spec, cdiv,
-                                  default_interpret, pad2d, pallas_kwargs,
-                                  vmem_scratch)
+                                  default_interpret, pad2d, pallas_kwargs)
 
 
 def _vsx_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps, bk):
@@ -97,7 +97,7 @@ def matmul_vsx_like(a: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mb * bm, nb * bn), out_dtype),
-        scratch_shapes=[vmem_scratch((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         **pallas_kwargs(
             interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -143,7 +143,7 @@ def matmul_vsx_like_packed(a: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mb * bm, nb * bn), out_dtype),
-        scratch_shapes=[vmem_scratch((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         **pallas_kwargs(
             interpret=interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from repro.core.tile_format import (TileFormat, as_tile_format,
                                     pack_nibbles, quantize_tiles)
 from repro.kernels.common import cdiv, default_interpret, pad2d, pallas_kwargs
+from repro.roofline.hw import current_target
 from repro.testing import faults
 
 
@@ -40,35 +41,57 @@ def _pack_kernel(x_ref, o_ref, *, transpose: bool):
     o_ref[0, 0] = tile
 
 
+def _sub_rows(b0: int, b1: int, itemsize: int) -> int:
+    """Rows of a tile's leading dim one pack step copies. A whole tile when
+    it is small; otherwise the largest lane-aligned divisor of ``b0`` whose
+    [rows, b1] block keeps the step's four VMEM buffers (input and output,
+    double-buffered) within half the declared VMEM limit — a weight tile the
+    planner sized for a GEMM's whole budget never has to fit four times."""
+    cap = current_target().vmem_limit_bytes // 8
+    lane = current_target().lane
+    if b0 * b1 * itemsize <= cap or b0 % lane:
+        return b0
+    fits = [r for r in range(lane, b0 + 1, lane)
+            if b0 % r == 0 and r * b1 * itemsize <= cap]
+    return max(fits) if fits else lane
+
+
 def _pack(x: jnp.ndarray, b0: int, b1: int, *, grid_order: str, layout: str,
           interpret: bool | None):
     """Shared packer. grid_order 'row': out [G0, G1, ...] = [dim0-tiles, dim1-tiles]
     (A's row-of-tiles order); 'col': out [G1, G0, ...] (B's column-of-tiles order).
+    Each tile is copied in ``rows``-high slabs of its leading (dim-0) extent.
     """
     if interpret is None:
         interpret = default_interpret()
     transpose = layout == "col"
     x_p = pad2d(x, b0, b1)
     g0, g1 = cdiv(x.shape[0], b0), cdiv(x.shape[1], b1)
+    rows = _sub_rows(b0, b1, x.dtype.itemsize)
+    steps = b0 // rows
     t0, t1 = (b1, b0) if transpose else (b0, b1)
-    if grid_order == "row":
-        grid = (g0, g1)
-        out_index = lambda i, j: (i, j, 0, 0)
-        out_shape = (g0, g1, t0, t1)
-    else:
-        grid = (g1, g0)
-        out_index = lambda j, i: (j, i, 0, 0)
-        out_shape = (g1, g0, t0, t1)
-    in_index = (lambda i, j: (i, j)) if grid_order == "row" else (lambda j, i: (i, j))
+    out_block = (1, 1, b1, rows) if transpose else (1, 1, rows, b1)
 
+    def tile_ids(p, q):  # grid (p, q) -> (dim-0 tile, dim-1 tile)
+        return (p, q) if grid_order == "row" else (q, p)
+
+    def out_index(p, q, r):
+        return (p, q, 0, r) if transpose else (p, q, r, 0)
+
+    def in_index(p, q, r):
+        i, j = tile_ids(p, q)
+        return (i * steps + r, j)
+
+    grid = (g0, g1, steps) if grid_order == "row" else (g1, g0, steps)
+    out_shape = (grid[0], grid[1], t0, t1)
     return pl.pallas_call(
         functools.partial(_pack_kernel, transpose=transpose),
         grid=grid,
-        in_specs=[pl.BlockSpec((b0, b1), in_index)],
-        out_specs=pl.BlockSpec((1, 1, t0, t1), out_index),
+        in_specs=[pl.BlockSpec((rows, b1), in_index)],
+        out_specs=pl.BlockSpec(out_block, out_index),
         out_shape=jax.ShapeDtypeStruct(out_shape, x.dtype),
         **pallas_kwargs(interpret=interpret,
-                        dimension_semantics=("parallel", "parallel")),
+                        dimension_semantics=("parallel",) * 3),
     )(x_p)
 
 
@@ -141,18 +164,23 @@ def pack_b_grouped(b: jnp.ndarray, bk, bn: int | None = None,
     b_p = jax.vmap(lambda be: pad2d(be, fmt.bk, fmt.bn))(b)
     kb, nb = cdiv(b.shape[1], fmt.bk), cdiv(b.shape[2], fmt.bn)
     t0, t1 = fmt.tile_shape
+    rows = _sub_rows(fmt.bk, fmt.bn, b.dtype.itemsize)
+    steps = fmt.bk // rows
+    out_block = ((1, 1, 1, fmt.bn, rows) if transpose
+                 else (1, 1, 1, rows, fmt.bn))
+
+    def out_index(ee, j, i, r):
+        return (ee, j, i, 0, r) if transpose else (ee, j, i, r, 0)
 
     packed = pl.pallas_call(
         functools.partial(_pack_kernel_grouped, transpose=transpose),
-        grid=(e, nb, kb),
-        in_specs=[pl.BlockSpec((1, fmt.bk, fmt.bn),
-                               lambda ee, j, i: (ee, i, j))],
-        out_specs=pl.BlockSpec((1, 1, 1, t0, t1),
-                               lambda ee, j, i: (ee, j, i, 0, 0)),
+        grid=(e, nb, kb, steps),
+        in_specs=[pl.BlockSpec((1, rows, fmt.bn),
+                               lambda ee, j, i, r: (ee, i * steps + r, j))],
+        out_specs=pl.BlockSpec(out_block, out_index),
         out_shape=jax.ShapeDtypeStruct((e, nb, kb, t0, t1), b.dtype),
         **pallas_kwargs(interpret=interpret,
-                        dimension_semantics=("parallel", "parallel",
-                                             "parallel")),
+                        dimension_semantics=("parallel",) * 4),
     )(b_p)
     if fmt.sub_byte:
         packed = pack_nibbles(packed)  # final storage step: 2 values/byte

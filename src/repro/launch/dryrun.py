@@ -1,11 +1,14 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU emulation: never take a chip
 
 """Multi-pod dry-run: prove every (arch x shape x mesh) cell lowers, compiles,
 fits memory, and extract the roofline terms from the compiled artifact.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init); do not move them. This module is the ONLY place the
+The three lines above MUST run before any other import (jax locks the device
+count and platform at first init); do not move them. The platform pin keeps
+this tool, and the children ``--all`` starts (they inherit it), off any
+attached accelerator. This module is the ONLY place the
 512-device emulation is enabled — tests and benches see the real host.
 
 Usage:

@@ -8,18 +8,12 @@ from __future__ import annotations
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions.
-
-    Newer jax wants explicit ``axis_types=(AxisType.Auto, ...)`` to opt out of
-    explicit-sharding mode; older releases (<= 0.4.x) have neither the kwarg
-    nor ``jax.sharding.AxisType`` and default to the same auto behaviour.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def compat_make_mesh(shape, axes, devices=None):
+    """jax.make_mesh with every axis in auto-sharding mode (the sharding
+    rules annotate; GSPMD partitions). ``devices`` defaults to all."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import reduced_config
+from repro.compile_cache import enable_compile_cache
 from repro.launch.train import preset_config
 from repro.models import build
 from repro.serve.engine import Engine, ServeConfig
@@ -33,6 +33,7 @@ def main(argv=None) -> int:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--ckpt-dir", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = preset_config(args.arch, args.preset)
     model = build(cfg)

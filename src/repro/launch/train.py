@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import DataConfig, MarkovLM, SyntheticLM
 from repro.launch.mesh import make_host_mesh
@@ -66,6 +67,22 @@ def preset_config(arch: str, preset: str):
     return cfg
 
 
+def init_sharded(model, mesh, key):
+    """Parameters and AdamW state, created under jit straight into their
+    mesh shardings (``parallel.sharding`` rules) — never materialized on
+    one device first, which at full width would not fit a single chip."""
+    params_shape = jax.eval_shape(model.init, key)
+    p_sh = shard_rules.named_shardings(model.cfg, params_shape, mesh)
+    o_sh = {"mu": p_sh, "nu": p_sh, "step": NamedSharding(mesh, P())}
+
+    def init(k):
+        params = model.init(k)
+        return params, opt.init_state(params)
+
+    with use_mesh(mesh):
+        return jax.jit(init, out_shardings=(p_sh, o_sh))(key)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
@@ -83,6 +100,7 @@ def main(argv=None) -> int:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--metrics-out", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = preset_config(args.arch, args.preset)
     model = build(cfg)
@@ -102,15 +120,8 @@ def main(argv=None) -> int:
         remat=True)
     step_fn = make_train_step(model, train_cfg)
 
+    params, opt_state = init_sharded(model, mesh, jax.random.PRNGKey(0))
     with use_mesh(mesh):
-        params = model.init(jax.random.PRNGKey(0))
-        opt_state = opt.init_state(params)
-        p_sh = shard_rules.named_shardings(cfg, params, mesh)
-        o_sh = {"mu": p_sh, "nu": p_sh,
-                "step": NamedSharding(mesh, P())}
-        params = jax.device_put(params, p_sh)
-        opt_state = jax.device_put(opt_state, o_sh)
-
         start_step = 0
         if args.ckpt_dir:
             latest = ckpt.latest_valid_step(args.ckpt_dir)

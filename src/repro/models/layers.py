@@ -52,24 +52,22 @@ _GATE_PAIR_KEYS = frozenset({"wg", "wu"})
 def _pack_dense(w: jnp.ndarray, compute, quantize=None) -> PackedWeight:
     """Pack one dense weight (2-D, or [L,K,N] scan-stacked) tile-major.
 
-    Uses the jnp packer on every backend: this runs once at load time, and
-    the buffer layout is identical to the Pallas packer's. Stacking and
-    ``quantize`` ("int8"/"int4", optional ":col" — quantized tiles + a
-    scale grid that scan-slices alongside the packed buffer) are handled
-    inside ``PackedWeight.pack``.
+    Runs once at load time on the execution backend's packer (the Pallas
+    packer on a TPU, the jnp reference elsewhere; identical buffer
+    layouts). Stacking and ``quantize`` ("int8"/"int4", optional ":col" —
+    quantized tiles + a scale grid that scan-slices alongside the packed
+    buffer) are handled inside ``PackedWeight.pack``.
     """
-    return PackedWeight.pack(w.astype(compute), backend="jnp",
-                             quantize=quantize)
+    return PackedWeight.pack(w.astype(compute), quantize=quantize)
 
 
 def _pack_grouped(w: jnp.ndarray, compute, key: str,
                   quantize=None) -> GroupedPackedWeight:
     """Pack one expert stack ([E,K,N], or [L,E,K,N] scan-stacked) grouped
-    tile-major in the compute dtype (jnp packer; load-time, runs once)."""
+    tile-major in the compute dtype (backend packer; load-time, runs once)."""
     w = w.astype(compute)
     return GroupedPackedWeight.pack(
-        w, backend="jnp", n_b_streams=2 if key in _GATE_PAIR_KEYS else 1,
-        quantize=quantize)
+        w, n_b_streams=2 if key in _GATE_PAIR_KEYS else 1, quantize=quantize)
 
 
 def pack_model_params(cfg: ModelConfig, params: dict, *, dtype=None,

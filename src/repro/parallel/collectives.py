@@ -72,18 +72,11 @@ def sp_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
 
     in_specs = (P(), P(None, seq_axis), P(None, seq_axis),
                 P(None, seq_axis), P())
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(
-            kernel, mesh=mesh, in_specs=in_specs, out_specs=P(),
-            check_vma=False,
-            axis_names={seq_axis},  # partial-manual: other axes stay automatic
-        )
-    else:  # older jax: jax.experimental API, auto= is the complement set
-        from jax.experimental.shard_map import shard_map as _shard_map
-        mapped = _shard_map(
-            kernel, mesh=mesh, in_specs=in_specs, out_specs=P(),
-            check_rep=False,
-            auto=frozenset(mesh.axis_names) - {seq_axis})
+    mapped = jax.shard_map(
+        kernel, mesh=mesh, in_specs=in_specs, out_specs=P(),
+        check_vma=False,
+        axis_names={seq_axis},  # partial-manual: other axes stay automatic
+    )
     return mapped(q, k_cache, v_cache, k_positions, q_positions)
 
 

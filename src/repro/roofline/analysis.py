@@ -408,8 +408,6 @@ def analyze(compiled, *, arch: str, shape: str, mesh_name: str, chips: int,
     counts loop bodies once).
     """
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # newer jax: one dict per device/prog
-        ca = ca[0] if ca else {}
     text = compiled.as_text()
     rolled = HloCostModel(text).rollup()
     flops = max(float(ca.get("flops", 0.0)), rolled.flops)

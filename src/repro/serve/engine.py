@@ -50,9 +50,9 @@ Production posture:
     HBM→VMEM traffic is 0.25x bf16. A ``":col"`` suffix on either
     ("int8:col" / "int4:col") switches to ONE f32 scale per Nb column.
     Scale contract: the [Nb, Kb] (grouped: [E, Nb, Kb]) tile-granularity
-    scale grid rides next to each packed buffer in the params tree, streams
-    through a BlockSpec mirroring B's index map (including the ragged
-    path's count-aware index pinning), and dequantizes each K-step's
+    scale grid rides next to each packed buffer in the params tree, sits
+    whole in the kernel's SMEM (read at the tile coordinates B's index map
+    fetched, live steps only on the ragged path), and dequantizes each K-step's
     partial product on the VMEM f32 accumulator BEFORE
     bias/activation/silu-gate; a col-granularity [Nb] ([E, Nb]) scale is
     K-invariant, hoists out of the K loop entirely, and multiplies the

@@ -58,12 +58,13 @@ def test_nibble_roundtrip_exhaustive_int4_range():
 
 
 def test_nibble_pairing_is_minor_axis_low_then_high():
-    """Element 2i lands in the LOW nibble, 2i+1 in the HIGH nibble of byte
-    i — the layout contract the in-kernel shift/mask unpack assumes."""
+    """Of a minor axis of length t, element i lands in the LOW nibble and
+    element i + t/2 in the HIGH nibble of byte i — the layout contract the
+    in-kernel shift/mask unpack assumes (low half, then high half)."""
     q = jnp.asarray([[1, -2, 3, -4]], jnp.int8)
     packed = np.asarray(pack_nibbles(q)).view(np.uint8)
-    want = np.asarray([[(1 & 0xF) | ((-2 & 0xF) << 4),
-                        (3 & 0xF) | ((-4 & 0xF) << 4)]], np.uint8)
+    want = np.asarray([[(1 & 0xF) | ((3 & 0xF) << 4),
+                        (-2 & 0xF) | ((-4 & 0xF) << 4)]], np.uint8)
     np.testing.assert_array_equal(packed, want)
     np.testing.assert_array_equal(np.asarray(unpack_nibbles(pack_nibbles(q))),
                                   np.asarray(q))

@@ -413,7 +413,7 @@ def test_int8_b_halves_working_set_at_fixed_blocks():
 def test_should_pack_bytes_aware_crossover():
     """int8 B halves the resident footprint: a B matrix just past the bf16
     pack crossover sits inside it at int8 (the VMEM-residency condition)."""
-    m, k, n = 4096, 1024, 2048  # k*n*2 just above vmem/32; *1 at the edge
+    m, k, n = 4096, 1024, 1024  # k*n*2 above vmem/32 (1.5 MiB); *1 below
     assert should_pack(m, k, n, "bfloat16", fused=True)
     assert not should_pack(m, k, n, "bfloat16", b_dtype="int8", fused=True)
     # far past the crossover both pack

@@ -61,8 +61,6 @@ def test_scan_amplification_matches_unroll():
     assert rs.flops == ru.flops == 8 * 2 * 64 ** 3
     # XLA's own analysis counts the body once (the bug this model fixes)
     ca = jax.jit(f_scan).lower(x, w).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-        ca = ca[0]
     assert ca["flops"] < rs.flops / 4
 
 
@@ -93,3 +91,72 @@ def test_roofline_terms_and_bottleneck():
     assert r.bottleneck == "compute"
     assert abs(r.useful_flops_ratio - 0.5) < 1e-9
     assert abs(r.roofline_fraction - 1.0) < 1e-9
+
+
+def test_hardware_model_keyed_by_device_kind():
+    from repro.roofline.hw import TARGETS, target_for
+    assert target_for("TPU v5 lite") is V5E
+    assert set(TARGETS) >= {"TPU v5 lite"}
+    try:
+        target_for("TPU v9 imaginary")
+    except KeyError as e:
+        assert "TPU v9 imaginary" in str(e)
+    else:
+        raise AssertionError("an unknown TPU kind must not fall back to v5e")
+
+
+def test_unknown_tpu_kind_raises_where_kernels_are_planned(monkeypatch):
+    """On an attached TPU of a kind the table does not hold, planning a
+    kernel is an error — never v5e's VMEM numbers by default."""
+    import types
+
+    from repro.core.planner import plan_gemm
+    from repro.roofline import hw
+    fake = types.SimpleNamespace(platform="tpu", device_kind="TPU v9 x")
+    monkeypatch.setattr(jax, "devices", lambda *a: [fake])
+    for fn in (hw.current_target, lambda: plan_gemm(128, 256, 256)):
+        try:
+            fn()
+        except KeyError:
+            continue
+        raise AssertionError("planned for an unknown TPU kind")
+
+
+def test_peak_flops_raises_for_unknown_dtype():
+    from repro.roofline.hw import peak_flops
+    assert peak_flops("bfloat16") == V5E.peak_bf16_flops
+    assert peak_flops("int8") == V5E.peak_int8_ops
+    try:
+        peak_flops("float8_e4m3fn")
+    except KeyError:
+        return
+    raise AssertionError("an unknown dtype must not get the bf16 peak")
+
+
+def test_vmem_budget_and_kernel_limit_share_one_source():
+    """The planner budgets within the limit every kernel declares, and the
+    declared limit fits the chip's physical VMEM."""
+    from repro.core.planner import GemmPlan, plan_gemm
+    from repro.kernels.common import tpu_compiler_params
+    limit = tpu_compiler_params(("parallel",)).vmem_limit_bytes
+    assert limit == V5E.vmem_limit_bytes <= V5E.vmem_capacity
+    assert GemmPlan(bm=128, bk=128, bn=128, dtype="bfloat16",
+                    acc_dtype="float32").vmem_budget == V5E.vmem_bytes
+    assert V5E.vmem_bytes < limit
+    for m, k, n in ((1, 8192, 2048), (2048, 8192, 2048), (512, 2048, 50304)):
+        assert plan_gemm(m, k, n, "bfloat16").vmem_working_set() <= \
+            V5E.vmem_bytes
+
+
+def test_interpret_mode_is_refused_on_a_tpu(monkeypatch):
+    from repro.kernels import common
+    assert common.pallas_kwargs(interpret=True,
+                                dimension_semantics=("parallel",)) == \
+        {"interpret": True}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert common.default_interpret() is False
+    try:
+        common.pallas_kwargs(interpret=True, dimension_semantics=("parallel",))
+    except RuntimeError:
+        return
+    raise AssertionError("interpret mode ran on a TPU backend")

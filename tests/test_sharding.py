@@ -21,10 +21,7 @@ def _mesh22():
 def _abstract_mesh(shape, names):
     # Mesh over repeated devices is invalid; use jax.sharding.AbstractMesh.
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(names))
-    except TypeError:  # older jax: one shape_tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def test_logical_spec_divisibility_fallback():
@@ -114,3 +111,30 @@ def test_pjit_forward_matches_single_device(rng):
             lambda p, b: model.forward(p, b, remat=False))(params, batch)
     np.testing.assert_allclose(np.asarray(plain), np.asarray(sharded),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_init_sharded_creates_state_on_the_mesh():
+    """Parameters and AdamW state come out of ONE jit already carrying the
+    sharding rules' shardings (nothing is built on one device first)."""
+    from repro.launch.train import init_sharded
+    cfg = reduced_config("olmo-1b")
+    model = build(cfg)
+    mesh = make_host_mesh(1)
+    params, opt_state = init_sharded(model, mesh, jax.random.PRNGKey(0))
+    want = rules.named_shardings(cfg, params, mesh)
+    for leaf, sh in zip(jax.tree.leaves(params), jax.tree.leaves(want)):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+    for leaf, sh in zip(jax.tree.leaves(opt_state["mu"]), jax.tree.leaves(want)):
+        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
+    ref = model.init(jax.random.PRNGKey(0))
+    for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(ref)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6,
+                                   atol=1e-7)
+
+
+def test_kernels_are_auto_dispatched_only_outside_a_mesh(monkeypatch):
+    from repro.core.contraction import kernel_backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kernel_backend()
+    with use_mesh(make_host_mesh(1)):
+        assert not kernel_backend()
