@@ -1,0 +1,298 @@
+"""The system under test, as the benchmark drives it.
+
+``repro``'s ``Engine`` over packed bfloat16 weights, behind its
+``ContinuousScheduler`` (paged KV pool, one batched decode program). The
+benchmark builds the weights itself from the seed (``chipbench.weights``),
+lays them out as the program's parameter tree and packs them in the same
+jitted call, so no float32 or unpacked copy is ever resident. It then drives
+``ContinuousScheduler.step()`` in its own open loop and timestamps every
+token by wrapping the public ``sample_tokens`` of its own ``Engine``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import time
+from typing import Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from chipbench import weights as W
+
+
+def program_config(arch: dict):
+    """The program's ``ModelConfig``: the registry entry with every size
+    taken from the benchmark's configuration file."""
+    from repro.configs import get_config
+    base = get_config(arch["registry"])
+    cfg = dataclasses.replace(
+        base, num_layers=arch["num_hidden_layers"],
+        d_model=arch["hidden_size"], num_heads=arch["num_attention_heads"],
+        num_kv_heads=arch["num_key_value_heads"], head_dim=arch["head_dim"],
+        d_ff=arch["intermediate_size"], vocab_size=arch["vocab_size"],
+        tie_embeddings=arch["tie_word_embeddings"],
+        rope_theta=arch["rope_theta"])
+    want = {"norm_type": arch["program_norm_type"], "mlp_type": "swiglu",
+            "family": "dense", "attention_type": "full", "use_bias": False,
+            "qk_norm": False, "parallel_block": False,
+            "pos_embedding": "rope", "compute_dtype": "bfloat16"}
+    got = {k: getattr(cfg, k) for k in want}
+    if got != want:
+        raise ValueError(f"{arch['registry']}: program config {got} is not "
+                         f"the architecture the reference computes {want}")
+    return cfg
+
+
+def _param_tree(key, arch: dict) -> dict:
+    """The program's parameter layout, filled from the benchmark's leaves."""
+    L, d = arch["num_hidden_layers"], arch["hidden_size"]
+    shapes = W.layer_shapes(arch)
+
+    def st(name):
+        return W.stacked(key, name, shapes[name], L)
+
+    def norm(name):
+        if not W.parametric_norm(arch):
+            return {}
+        return {"scale": W.stacked(key, name, (d,), L).astype(jnp.float32)}
+
+    g = W.global_weights(key, arch)
+    params = {
+        "embed": {"table": g["embed"]},
+        "layers": {
+            "norm1": norm("norm1"),
+            "attn": {"wq": st("wq"), "wk": st("wk"), "wv": st("wv"),
+                     "wo": st("wo")},
+            "norm2": norm("norm2"),
+            "mlp": {"wg": st("w_gate"), "wu": st("w_up"), "wo": st("w_down")},
+        },
+        "final_norm": ({"scale": g["final_norm"].astype(jnp.float32)}
+                       if "final_norm" in g else {}),
+    }
+    if "head" in g:
+        params["head"] = {"table": g["head"]}
+    return params
+
+
+def check_layout(model, arch: dict) -> None:
+    """The benchmark's tree must have exactly the program's structure and
+    shapes (its dtypes are the served ones, so they may differ)."""
+    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    got = jax.eval_shape(lambda k: _param_tree(k, arch),
+                         jax.random.PRNGKey(0))
+    ws = jax.tree.map(lambda a: a.shape, want)
+    gs = jax.tree.map(lambda a: a.shape, got)
+    if ws != gs:
+        raise ValueError(f"parameter layout differs from the program's: "
+                         f"{gs} vs {ws}")
+
+
+def build_params(model, arch: dict, seed: int, quantize: Optional[str] = None):
+    """Served weights on the device in one jitted call: draw, lay out, pack
+    (``quantize`` switches on the program's own int8 weight path)."""
+    from repro.models.layers import pack_model_params
+    check_layout(model, arch)
+
+    @jax.jit
+    def make(key):
+        return pack_model_params(model.cfg, _param_tree(key, arch),
+                                 quantize=quantize)
+
+    params = make(W.root_key(seed))
+    jax.block_until_ready(params)
+    return params
+
+
+class TokenClock:
+    """Wraps ``engine.sample_tokens``: blocks until the tokens are on the
+    host, then records ``(request_id, index, t, token)`` once per pair (the
+    batched step pads idle rows with a live row's pair) and each call's
+    committed pairs. It also keeps, on the device, each row's logits at the
+    vocabulary ids ``probe_ids`` (one small gather per call), so the check
+    can compare the logits the timed path produced with the reference's.
+    Once ``rows_in_use`` is set (a callable giving, for a batch as wide as
+    its result, which rows hold a request), rows that do not are skipped:
+    they compute garbage under a live row's pair."""
+
+    def __init__(self, engine, probe_ids, clock=time.perf_counter):
+        from jax.profiler import TraceAnnotation
+        self.events: List[Tuple[int, int, float, int]] = []
+        self.calls: List[Tuple[float, int, List[Tuple[int, int]]]] = []
+        self._seen = set()
+        self._probes: list = []                  # device [width, K] per call
+        self._probed: Dict[Tuple[int, int], Tuple[int, int]] = {}
+        self.rows_in_use = None
+        ids = jnp.asarray(probe_ids, jnp.int32)
+        take = jax.jit(lambda logits: logits[:, ids].astype(jnp.float32))
+        original = engine.sample_tokens
+
+        def sample_tokens(logits, request_ids, step):
+            with TraceAnnotation("engine.sample_tokens"):
+                out = original(logits, request_ids, step)
+                probe = take(logits)
+                host = np.asarray(out)
+            t = clock()
+            rids = np.atleast_1d(np.asarray(request_ids))
+            steps = np.broadcast_to(np.asarray(step), rids.shape)
+            in_use = self.rows_in_use() if self.rows_in_use else None
+            if in_use is not None and len(in_use) != len(rids):
+                in_use = None
+            pairs = []
+            for row, (r, s, tok) in enumerate(zip(rids.tolist(), steps.tolist(),
+                                                  host.tolist())):
+                if (r, s) in self._seen or (in_use is not None
+                                            and not in_use[row]):
+                    continue
+                self._seen.add((r, s))
+                self.events.append((r, s, t, tok))
+                self._probed[(r, s)] = (len(self._probes), row)
+                pairs.append((r, s))
+            self._probes.append(probe)
+            self.calls.append((t, int(logits.shape[0]), pairs))
+            return out
+
+        engine.sample_tokens = sample_tokens
+
+    def probed_logits(self, request_id: int, n: int) -> np.ndarray:
+        """[n, K]: the logits at the probe ids from which tokens 0..n-1 of
+        the request were sampled."""
+        rows = []
+        for s in range(n):
+            if (request_id, s) not in self._probed:
+                raise KeyError(f"no logits captured for token {s} of request "
+                               f"{request_id}")
+            call, row = self._probed[(request_id, s)]
+            if not isinstance(self._probes[call], np.ndarray):
+                self._probes[call] = np.asarray(self._probes[call])
+            rows.append(self._probes[call][row])
+        return np.stack(rows)
+
+
+def annotate_prefill(engine) -> None:
+    from jax.profiler import TraceAnnotation
+    original = engine.prefill_request
+
+    def prefill_request(tokens):
+        with TraceAnnotation("engine.prefill_request"):
+            return original(tokens)
+
+    engine.prefill_request = prefill_request
+
+
+class CompileCounter:
+    """Counts programs built (compiled, or loaded from the persistent
+    cache) through ``jax.monitoring``'s duration events."""
+
+    EVENTS = ("/jax/core/compile/backend_compile_duration",
+              "/jax/compilation_cache/cache_retrieval_time_sec")
+
+    def __init__(self):
+        self.count = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **_):
+        if event in self.EVENTS:
+            self.count += 1
+
+
+@dataclasses.dataclass
+class Server:
+    engine: object
+    sched: object
+    clock: TokenClock
+
+
+def build_server(arch: dict, serving: dict, seed: int, probe_ids,
+                 quantize: Optional[str] = None) -> Server:
+    from repro.models import build
+    from repro.serve.engine import Engine, ServeConfig
+    from repro.serve.scheduler import ContinuousConfig, ContinuousScheduler
+    model = build(program_config(arch))
+    params = build_params(model, arch, seed, quantize)
+    engine = Engine(model, params, ServeConfig(
+        max_len=serving["max_len"], temperature=0.0,
+        cache_dtype=serving["cache_dtype"], pack_weights=False))
+    clock = TokenClock(engine, probe_ids)
+    annotate_prefill(engine)
+    sched = ContinuousScheduler(engine, ContinuousConfig(
+        queue_capacity=serving["queue_capacity"],
+        max_live=serving["max_live"], block_size=serving["block_size"],
+        default_max_new_tokens=1))
+    # A row holds a request while its block table names a real block (the
+    # pool's block 0 is the null block every idle row points at).
+    clock.rows_in_use = lambda: sched.kv.tables.max(axis=1) > 0
+    return Server(engine, sched, clock)
+
+
+WARM_ID = 1 << 30   # warm-up request ids, apart from the traffic's
+
+
+def warm_up(server: Server, prompt_lengths) -> None:
+    """Serve one request of every prompt length the traffic uses, two
+    tokens each: every program of the window (each prefill length, the
+    samplers at both widths, insert, the batched step, scrub) is built
+    here."""
+    from repro.serve.requests import Request
+    for i, s in enumerate(sorted(set(prompt_lengths))):
+        server.sched.submit(Request(request_id=WARM_ID + i,
+                                    tokens=np.arange(s, dtype=np.int32),
+                                    max_new_tokens=2))
+    server.sched.drain()
+
+
+@dataclasses.dataclass
+class WindowLog:
+    start: float
+    end: float
+    due: Dict[int, float]
+    late_s: List[float]
+    ticks: List[Tuple[float, float]]
+    queue_mid: Optional[int] = None
+    queue_end: Optional[int] = None
+    drained_at: float = 0.0
+
+
+def run_window(server: Server, arrivals, lead_in_s: float, seconds: float,
+               on_tick=None, clock=time.perf_counter,
+               sleep=time.sleep) -> WindowLog:
+    """Open loop: each arrival is submitted at the first tick after it is
+    due; after the window closes no new request arrives and the requests in
+    flight are drained, so each has a whole timeline."""
+    from jax.profiler import TraceAnnotation
+    from repro.serve.requests import Request
+    sched = server.sched
+    reqs = [Request(request_id=a.request_id, tokens=a.tokens,
+                    max_new_tokens=a.max_new_tokens) for a in arrivals]
+    t0 = clock()
+    log = WindowLog(start=t0 + lead_in_s, end=t0 + lead_in_s + seconds,
+                    due={a.request_id: t0 + a.due_s for a in arrivals},
+                    late_s=[], ticks=[])
+    mid = log.start + seconds / 2
+    i, n = 0, len(reqs)
+    base = len(sched.results)
+    while True:
+        now = clock()
+        while i < n and t0 + arrivals[i].due_s <= now:
+            sched.submit(reqs[i])
+            log.late_s.append(now - (t0 + arrivals[i].due_s))
+            i += 1
+        if log.queue_mid is None and now >= mid:
+            log.queue_mid = sched.stats()["queued"]
+        if log.queue_end is None and now >= log.end:
+            log.queue_end = sched.stats()["queued"]
+        if on_tick is not None:
+            on_tick(now)
+        if i == len(sched.results) - base:      # nothing in flight
+            if i >= n:
+                break
+            sleep(max(0.0, t0 + arrivals[i].due_s - clock()))
+            continue
+        ts = clock()
+        with TraceAnnotation("sched.step"):
+            sched.step()
+        log.ticks.append((ts, clock()))
+    log.drained_at = clock()
+    if log.queue_end is None:
+        log.queue_end = 0
+    return log
