@@ -218,11 +218,16 @@ class RequestRecord:
     retries: int = 0                  # step attempts that failed retryably
     tokens_emitted: int = 0
     latency_s: float = 0.0            # admission -> terminal (terminal only)
+    # On the serving layer's clock (None where it does not stamp them):
+    queued_t: Optional[float] = None       # entered the queue
+    admit_t: Optional[float] = None        # its admission into a row began
+    first_token_t: Optional[float] = None  # its first token was committed
 
     def as_dict(self) -> dict:
         return {"status": self.status, "retries": self.retries,
                 "tokens_emitted": self.tokens_emitted,
-                "latency_s": self.latency_s,
+                "latency_s": self.latency_s, "queued_t": self.queued_t,
+                "admit_t": self.admit_t, "first_token_t": self.first_token_t,
                 "events": [dict(e) for e in self.events]}
 
 
@@ -263,13 +268,14 @@ class ServeRegistry:
             request_id=request_id, status="queued")
         return rec
 
-    def admitted(self, request_id: int, step: int = 0,
-                 detail: str = "") -> None:
+    def admitted(self, request_id: int, step: int = 0, detail: str = "",
+                 queued_t: Optional[float] = None) -> None:
         with self._lock:
             self._counters["offered"] += 1
             self._counters["admitted"] += 1
             rec = self._insert(request_id)
             rec.status = "queued"
+            rec.queued_t = queued_t
             rec.events.append({"event": "admitted", "step": step,
                                "detail": detail})
 
@@ -283,11 +289,16 @@ class ServeRegistry:
             rec.status = "shed"
             rec.events.append({"event": "shed", "step": 0, "detail": detail})
 
-    def live(self, request_id: int) -> None:
+    def live(self, request_id: int, admit_t: Optional[float] = None,
+             first_token_t: Optional[float] = None) -> None:
+        """The request holds a decode slot; the continuous scheduler also
+        stamps when its admission began and its first token was committed."""
         with self._lock:
             rec = self._records.get(request_id)
             if rec is not None:
                 rec.status = "live"
+                rec.admit_t = admit_t
+                rec.first_token_t = first_token_t
 
     def retry(self, request_id: int, step: int, cause: str,
               backoff_s: float) -> None:
@@ -352,12 +363,6 @@ class ServeRegistry:
     def counters(self) -> Dict[str, int]:
         with self._lock:
             return dict(self._counters)
-
-    def open_requests(self) -> int:
-        """Retained records not yet terminal (queued or live)."""
-        with self._lock:
-            return sum(1 for r in self._records.values()
-                       if r.status not in TERMINAL_STATES)
 
     @property
     def dropped(self) -> int:

@@ -103,6 +103,7 @@ def flash_attention(q: jnp.ndarray,
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           window=window, sq=sq, skv=skv, bq=bq_, bkv=bkv_,
                           kv_steps=kv_steps),
+        name="flash_attention",
         grid=(b * h, q_steps, kv_steps),
         in_specs=[
             pl.BlockSpec((1, bq_, 1, d),

@@ -224,6 +224,7 @@ def gemm_grouped_packed(a: jnp.ndarray,
         functools.partial(_grouped_kernel, k_steps=kb, n_blocks=nb, fmt=fmt,
                           epilogue=epilogue, has_bias=has_bias,
                           has_scale=has_scale, has_gate=has_gate),
+        name="gemm_grouped_packed",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, bm, bn), lambda ee, i, j, kk: (ee, i, j)),
@@ -405,6 +406,7 @@ def gemm_grouped_packed_ragged(a: jnp.ndarray,
                           segments=s, bm=bm, fmt=fmt, epilogue=epilogue,
                           has_bias=has_bias, has_scale=has_scale,
                           has_gate=has_gate),
+        name="gemm_grouped_packed_ragged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((grp, mb * bm, nb * bn), out_dtype),
         **pallas_kwargs(

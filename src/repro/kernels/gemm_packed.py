@@ -154,6 +154,7 @@ def gemm_packed(a_packed: jnp.ndarray,
         functools.partial(_packed_kernel, alpha=alpha, beta=beta, k_steps=kb,
                           layout_a=layout_a, fmt=fmt, epilogue=epilogue,
                           has_c=has_c, has_bias=has_bias),
+        name="gemm_packed",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -238,6 +239,7 @@ def gemm_packed_fused_a(a: jnp.ndarray,
         functools.partial(_fused_a_kernel, alpha=alpha, beta=beta, k_steps=kb,
                           n_blocks=nb, fmt=fmt, epilogue=epilogue, has_c=has_c,
                           has_bias=has_bias, has_scale=has_scale),
+        name="gemm_packed_fused_a",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),

@@ -103,6 +103,7 @@ def gemm_tiled(a: jnp.ndarray,
         functools.partial(_gemm_kernel, alpha=alpha, beta=beta, k_steps=kb,
                           epilogue=epilogue, has_c=has_c,
                           has_bias=has_bias),
+        name="gemm_tiled",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),

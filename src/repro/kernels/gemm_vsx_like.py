@@ -90,6 +90,7 @@ def matmul_vsx_like(a: jnp.ndarray,
 
     out = pl.pallas_call(
         functools.partial(_vsx_kernel, k_steps=kb, bk=bk),
+        name="matmul_vsx_like",
         grid=(mb, nb, kb),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -136,6 +137,7 @@ def matmul_vsx_like_packed(a: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_vsx_packed_kernel, k_steps=kb, bk=bk,
                           layout_b=layout_b),
+        name="matmul_vsx_like_packed",
         grid=(mb, nb, kb),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

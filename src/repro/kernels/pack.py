@@ -86,6 +86,7 @@ def _pack(x: jnp.ndarray, b0: int, b1: int, *, grid_order: str, layout: str,
     out_shape = (grid[0], grid[1], t0, t1)
     return pl.pallas_call(
         functools.partial(_pack_kernel, transpose=transpose),
+        name="pack",
         grid=grid,
         in_specs=[pl.BlockSpec((rows, b1), in_index)],
         out_specs=pl.BlockSpec(out_block, out_index),
@@ -174,6 +175,7 @@ def pack_b_grouped(b: jnp.ndarray, bk, bn: int | None = None,
 
     packed = pl.pallas_call(
         functools.partial(_pack_kernel_grouped, transpose=transpose),
+        name="pack_b_grouped",
         grid=(e, nb, kb, steps),
         in_specs=[pl.BlockSpec((1, rows, fmt.bn),
                                lambda ee, j, i, r: (ee, i * steps + r, j))],

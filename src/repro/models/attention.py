@@ -96,6 +96,7 @@ def self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     return out
 
 
+@jax.named_scope("kv_write")
 def cache_from_prefill(cfg: ModelConfig, k: jnp.ndarray, v: jnp.ndarray,
                        max_len: int, dtype) -> dict:
     """Build the decode ring-buffer cache from full-prefill K/V [B,S,Hkv,D].
@@ -165,6 +166,7 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     slots = cache["k"].shape[1]
     slot = (pos % slots)  # [B]
 
+    @jax.named_scope("kv_write")
     def write(buf, new):
         onehot = jax.nn.one_hot(slot, slots, dtype=buf.dtype)  # [B, slots]
         keep = 1.0 - onehot

@@ -219,6 +219,7 @@ def mlp_params(cfg: ModelConfig, key) -> dict:
     return p
 
 
+@jax.named_scope("mlp")
 def apply_mlp(cfg: ModelConfig, p: dict, x: jnp.ndarray,
               epilogue_shard: bool = True) -> jnp.ndarray:
     if cfg.mlp_type in ("swiglu", "geglu"):
@@ -273,6 +274,7 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
     return shard(x, "batch")
 
 
+@jax.named_scope("lm_head")
 def lm_logits(cfg: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
     head = params.get("head_packed")  # load-time-packed LM head (serving)
     if head is None:
@@ -295,6 +297,7 @@ def lm_logits(cfg: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
 # Chunked exact attention (memory-bounded jnp lowering)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("attention")
 def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       causal: bool, window: Optional[int] = None,
                       prefix_len: int = 0, q_offset: int = 0,

@@ -173,6 +173,7 @@ class Engine:
         # the serving step at small batch sizes.
         base, temp = jax.random.PRNGKey(cfg.seed), cfg.temperature
 
+        @jax.named_scope("sample")
         def _sampled(logits, rids, steps):
             def one(rid, s, row):
                 key = jax.random.fold_in(jax.random.fold_in(base, rid), s)
@@ -180,8 +181,8 @@ class Engine:
             return jax.vmap(one)(rids, steps, logits).astype(jnp.int32)
 
         self._sampled = jax.jit(_sampled)
-        self._argmax = jax.jit(
-            lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        self._argmax = jax.jit(jax.named_scope("sample")(
+            lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32)))
 
     def health_report(self) -> Dict[str, dict]:
         """The dispatch-health registry's degradation report.

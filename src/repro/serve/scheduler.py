@@ -42,7 +42,16 @@ preserving EVERY clause of the front-end's request-lifecycle contract:
 
 Every preemption, resume, and bisection verdict lands in the process-global
 ``repro.core.health.SERVE`` registry and surfaces through
-``Engine.serve_report()``.
+``Engine.serve_report()``, with each request's ``queued_t``, ``admit_t`` and
+``first_token_t`` on the scheduler's clock.
+
+Under the JAX profiler each tick is a host span ``serve.tick`` holding its
+phases in order: ``serve.admit`` per admission (argument ``request_id``;
+it holds ``serve.prefill`` and the first token's ``serve.token_wait`` and
+``serve.commit``), ``serve.kv_grow``, ``serve.step_dispatch``,
+``serve.token_wait`` (the host blocked on the chip) and ``serve.commit``.
+The batched step names its KV traffic ``kv_gather`` and ``kv_scatter``
+(``jax.named_scope``).
 """
 from __future__ import annotations
 
@@ -55,6 +64,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import health
 from repro.serve.frontend import RETRYABLE_CLASSES, VirtualClock  # noqa: F401
@@ -177,10 +187,12 @@ class ContinuousScheduler:
                 g = pool[:, tables]          # [L, B, MB, bs, Hkv, D]
                 return g.reshape(g.shape[0], B, max_len, *g.shape[4:])
 
-            caches = {"kv": {"k": gather(pool_k), "v": gather(pool_v)}}
+            with jax.named_scope("kv_gather"):
+                caches = {"kv": {"k": gather(pool_k), "v": gather(pool_v)}}
             logits, new = model.decode(params, caches, tokens, pos)
             dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
 
+            @jax.named_scope("kv_scatter")
             def scatter(pool, leaf):
                 idx = pos[None, :, None, None, None]
                 written = jnp.take_along_axis(leaf, idx, axis=2)[:, :, 0]
@@ -197,11 +209,13 @@ class ContinuousScheduler:
                                   compute_dtype)
                 return g.reshape(g.shape[0], B, max_len, *g.shape[4:])
 
-            caches = {"kv": {"k": gather(pool_k, scale_k),
-                             "v": gather(pool_v, scale_v)}}
+            with jax.named_scope("kv_gather"):
+                caches = {"kv": {"k": gather(pool_k, scale_k),
+                                 "v": gather(pool_v, scale_v)}}
             logits, new = model.decode(params, caches, tokens, pos)
             dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
 
+            @jax.named_scope("kv_scatter")
             def scatter(pool, scales, leaf):
                 idx = pos[None, :, None, None, None]
                 written = jnp.take_along_axis(leaf, idx, axis=2)[:, :, 0]
@@ -239,8 +253,9 @@ class ContinuousScheduler:
         if len(self._queue) >= self.cfg.queue_capacity:
             return self._shed(
                 request, f"queue full (capacity {self.cfg.queue_capacity})")
-        health.SERVE.admitted(rid)
-        self._queue.append(_QEntry(req=request, admit_t=self._clock(),
+        now = self._clock()
+        health.SERVE.admitted(rid, queued_t=now)
+        self._queue.append(_QEntry(req=request, admit_t=now,
                                    admit_seq=self._admit_seq, emitted=[]))
         self._admit_seq += 1
         return None
@@ -314,6 +329,7 @@ class ContinuousScheduler:
         """Move one queue entry into a batch row: allocate KV for its
         occupied positions, prefill the prompt (and replay the generated
         prefix if resuming), guarded exactly like the front-end's step."""
+        admit_t = self._clock()
         req = entry.req
         rid = req.request_id
         S = req.tokens.shape[0]
@@ -356,6 +372,33 @@ class ContinuousScheduler:
                 self._queue.appendleft(entry)
                 return
             break
+        with TraceAnnotation("serve.prefill"):
+            logits = self._prefill(entry, slot, done)
+        if logits is None:
+            return
+        self._live[row] = slot
+        if entry.preempted:
+            health.SERVE.resumed(rid, step=k)
+        else:
+            with TraceAnnotation("serve.token_wait"):
+                tok = np.asarray(self.engine.sample_tokens(logits, [rid],
+                                                           step=0))
+            with TraceAnnotation("serve.commit"):
+                slot.emitted.append(int(tok[0]))
+                health.SERVE.live(rid, admit_t=admit_t,
+                                  first_token_t=self._clock())
+                if len(slot.emitted) >= slot.budget:
+                    done[rid] = self._finalize_slot(slot, "completed")
+
+    def _prefill(self, entry: _QEntry, slot: _CSlot,
+                 done: Dict[int, RequestResult]):
+        """Prefill the slot's prompt into its row (replaying a resumed
+        request's generated prefix): the prompt's last logits, or None when
+        the request was evicted."""
+        req, row = entry.req, slot.row
+        rid = req.request_id
+        S = req.tokens.shape[0]
+        k = len(entry.emitted)
         # Prefill (+ teacher-forced replay of the resumed prefix): pure in
         # (prompt, prefix), so the whole sequence retries as a unit (pool
         # writes are deterministic overwrites, safe to redo). A quantized
@@ -401,21 +444,13 @@ class ContinuousScheduler:
                 self._live[row] = slot
                 done[rid] = self._finalize_slot(
                     slot, "evicted", f"{cause}: {exc}")
-                return
+                return None
             break
         if not self.kv.quantize:
             # Quantized pools already committed in the guarded loop above
             # (an insert here would re-quantize dequantized values — drift).
             self.kv.insert_dense(row, caches)
-        self._live[row] = slot
-        if entry.preempted:
-            health.SERVE.resumed(rid, step=k)
-        else:
-            health.SERVE.live(rid)
-            tok = self.engine.sample_tokens(logits, [rid], step=0)
-            slot.emitted.append(int(np.asarray(tok)[0]))
-            if len(slot.emitted) >= slot.budget:
-                done[rid] = self._finalize_slot(slot, "completed")
+        return logits
 
     def _admissions(self, done: Dict[int, RequestResult]) -> None:
         now = self._clock()
@@ -445,7 +480,9 @@ class ContinuousScheduler:
             self._queue.popleft()
             row = self._free_row()
             before = len(done)
-            self._admit_one(entry, row, done)
+            with TraceAnnotation("serve.admit",
+                                 request_id=entry.req.request_id):
+                self._admit_one(entry, row, done)
             if row not in self._live and len(done) == before:
                 break  # entry went back to the queue head; stop admitting
 
@@ -456,20 +493,22 @@ class ContinuousScheduler:
         the batch, grow KV (preempting under exhaustion), then advance every
         live row one token through the shared batched program. Returns newly
         finalized results."""
-        done: Dict[int, RequestResult] = {}
-        self._admissions(done)
-        now = self._clock()
-        for row in sorted(self._live):
-            slot = self._live[row]
-            if slot.deadline_s is not None \
-                    and now - slot.admit_t > slot.deadline_s:
-                done[slot.req.request_id] = self._finalize_slot(
-                    slot, "deadline_miss",
-                    f"deadline {slot.deadline_s:.3f}s elapsed")
-        self._grow_all(done)
-        if self._live:
-            self._batched_step(done)
-        return done
+        with TraceAnnotation("serve.tick"):
+            done: Dict[int, RequestResult] = {}
+            self._admissions(done)
+            now = self._clock()
+            for row in sorted(self._live):
+                slot = self._live[row]
+                if slot.deadline_s is not None \
+                        and now - slot.admit_t > slot.deadline_s:
+                    done[slot.req.request_id] = self._finalize_slot(
+                        slot, "deadline_miss",
+                        f"deadline {slot.deadline_s:.3f}s elapsed")
+            with TraceAnnotation("serve.kv_grow"):
+                self._grow_all(done)
+            if self._live:
+                self._batched_step(done)
+            return done
 
     def _grow_all(self, done: Dict[int, RequestResult]) -> None:
         """Ensure every live row's next write position is block-backed,
@@ -515,45 +554,52 @@ class ContinuousScheduler:
         """Advance the whole batch one token: guarded shared attempt with
         classified retry, then bisection on retry exhaustion."""
         cfg = self.cfg
-        tokens = np.zeros((cfg.max_live, 1), np.int32)
-        pos = np.zeros((cfg.max_live,), np.int32)
-        for row, slot in self._live.items():
-            tokens[row, 0] = slot.emitted[-1]
-            pos[row] = slot.req.tokens.shape[0] + len(slot.emitted) - 1
-        live_rows = sorted(self._live)
-        attempts = 0
-        while True:
-            try:
-                faults.maybe_fail("batch_step")
-                kv = self.kv
-                if kv.quantize:
-                    logits, pk, pv, sk, sv = self._jit_step(
-                        self.engine.params, kv.pool["k"], kv.pool["v"],
-                        kv.scales["k"], kv.scales["v"], kv.device_tables(),
-                        jnp.asarray(tokens), jnp.asarray(pos))
-                else:
-                    sk = sv = None
-                    logits, pk, pv = self._jit_step(
-                        self.engine.params, kv.pool["k"], kv.pool["v"],
-                        kv.device_tables(), jnp.asarray(tokens),
-                        jnp.asarray(pos))
-            except Exception as exc:  # noqa: BLE001 — classify, retry/bisect
-                cause = health.classify_failure(exc)
-                if cause in RETRYABLE_CLASSES \
-                        and attempts < cfg.max_retries:
-                    attempts += 1
-                    backoff = min(cfg.backoff_base_s * (2 ** (attempts - 1)),
-                                  cfg.backoff_cap_s)
-                    for row in live_rows:
-                        slot = self._live[row]
-                        health.SERVE.retry(slot.req.request_id,
-                                           len(slot.emitted), cause, backoff)
-                        slot.retries += 1
-                    self._sleep(backoff)
-                    continue
-                self._bisect(done, cause, exc)
-                return
-            break
+        failure = None
+        with TraceAnnotation("serve.step_dispatch"):
+            tokens = np.zeros((cfg.max_live, 1), np.int32)
+            pos = np.zeros((cfg.max_live,), np.int32)
+            for row, slot in self._live.items():
+                tokens[row, 0] = slot.emitted[-1]
+                pos[row] = slot.req.tokens.shape[0] + len(slot.emitted) - 1
+            live_rows = sorted(self._live)
+            attempts = 0
+            while True:
+                try:
+                    faults.maybe_fail("batch_step")
+                    kv = self.kv
+                    if kv.quantize:
+                        logits, pk, pv, sk, sv = self._jit_step(
+                            self.engine.params, kv.pool["k"], kv.pool["v"],
+                            kv.scales["k"], kv.scales["v"],
+                            kv.device_tables(), jnp.asarray(tokens),
+                            jnp.asarray(pos))
+                    else:
+                        sk = sv = None
+                        logits, pk, pv = self._jit_step(
+                            self.engine.params, kv.pool["k"], kv.pool["v"],
+                            kv.device_tables(), jnp.asarray(tokens),
+                            jnp.asarray(pos))
+                except Exception as exc:  # noqa: BLE001 — retry, bisect
+                    cause = health.classify_failure(exc)
+                    if cause in RETRYABLE_CLASSES \
+                            and attempts < cfg.max_retries:
+                        attempts += 1
+                        backoff = min(
+                            cfg.backoff_base_s * (2 ** (attempts - 1)),
+                            cfg.backoff_cap_s)
+                        for row in live_rows:
+                            slot = self._live[row]
+                            health.SERVE.retry(slot.req.request_id,
+                                               len(slot.emitted), cause,
+                                               backoff)
+                            slot.retries += 1
+                        self._sleep(backoff)
+                        continue
+                    failure = (cause, exc)
+                break
+        if failure is not None:
+            self._bisect(done, *failure)
+            return
         # Commit only after a clean shared step (retries/bisection never see
         # a half-mutated pool — the jit'd step returned NEW pool arrays).
         self.kv.pool["k"], self.kv.pool["v"] = pk, pv
@@ -636,14 +682,17 @@ class ContinuousScheduler:
             idx = row if row_index is None else row_index[row]
             rids[idx] = self._live[row].req.request_id
             steps[idx] = len(self._live[row].emitted)
-        toks = np.asarray(self.engine.sample_tokens(logits_b, rids, steps))
-        for row in commit:
-            idx = row if row_index is None else row_index[row]
-            slot = self._live[row]
-            slot.emitted.append(int(toks[idx]))
-            if len(slot.emitted) >= slot.budget:
-                done[slot.req.request_id] = self._finalize_slot(
-                    slot, "completed")
+        with TraceAnnotation("serve.token_wait"):
+            toks = np.asarray(self.engine.sample_tokens(logits_b, rids,
+                                                        steps))
+        with TraceAnnotation("serve.commit"):
+            for row in commit:
+                idx = row if row_index is None else row_index[row]
+                slot = self._live[row]
+                slot.emitted.append(int(toks[idx]))
+                if len(slot.emitted) >= slot.budget:
+                    done[slot.req.request_id] = self._finalize_slot(
+                        slot, "completed")
 
     # ----- driving loops --------------------------------------------------
 

@@ -365,6 +365,107 @@ def test_oversized_request_rejected_loudly(engine, no_fault):
 
 
 # ---------------------------------------------------------------------------
+# Request timings and the host spans of a tick
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("held_by", ["rows", "kv_blocks"])
+def test_request_times_show_the_queue_wait(engine, no_fault, held_by):
+    """Under the fake clock every time is exact: request 1 queues at 0.1 s
+    behind request 0, which holds the only row (or the only KV block) from
+    its admission at 0.5 s until it completes in the 0.6 s tick; request 1
+    is admitted at the next tick, 0.7 s, and waited 0.6 s."""
+    kw = ({"max_live": 1} if held_by == "rows"
+          else {"max_live": 2, "num_kv_blocks": 1})
+    cs, clock = _sched(engine, **kw)
+    cs.submit(Request(request_id=0, tokens=np.arange(4, dtype=np.int32),
+                      max_new_tokens=3))
+    clock.sleep(0.1)
+    cs.submit(Request(request_id=1, tokens=np.arange(4, dtype=np.int32),
+                      max_new_tokens=3))
+    clock.sleep(0.4)
+    ticks = 0
+    while cs._queue or cs._live:
+        cs.step()
+        ticks += 1
+        clock.sleep(0.1)
+    assert ticks == 4
+    recs = engine.serve_report()["requests"]
+    times = {int(r): (rec["queued_t"], rec["admit_t"], rec["first_token_t"])
+             for r, rec in recs.items()}
+    assert times == {0: pytest.approx((0.0, 0.5, 0.5)),
+                     1: pytest.approx((0.1, 0.7, 0.7))}
+    for queued, admit, first in times.values():
+        assert queued <= admit <= first
+    _assert_conservation(cs, 2)
+
+
+# The host spans of one tick, in the order they open: admissions (each
+# holding its prefill and its first token), then KV growth, the batched
+# step's dispatch, the wait for its tokens, and their commit.
+TICK_PHASES = ("serve.admit", "serve.kv_grow", "serve.step_dispatch",
+               "serve.token_wait", "serve.commit")
+ADMIT_PHASES = ("serve.prefill", "serve.token_wait", "serve.commit")
+
+
+def _host_spans(trace_dir):
+    import glob
+    from jax.profiler import ProfileData
+    path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans.extend((e.start_ns, e.end_ns, e.name, dict(e.stats))
+                             for e in line.events
+                             if e.name.startswith("serve."))
+    return sorted(spans, key=lambda s: (s[0], -s[1]))
+
+
+def test_tick_spans_nest_in_order_under_the_profiler(engine, no_fault,
+                                                     tmp_path):
+    """The CPU profiler records the scheduler's host spans: every phase
+    lies inside its tick in the documented order, each admission names
+    its request, and none reuses a benchmark span's name (the benchmark
+    counts ticks by its own ``sched.step``)."""
+    from chipbench.trace import SPAN_NAMES
+    cs, _ = _sched(engine)
+    reqs = _requests(4, seed=11)
+    for r in reqs:
+        cs.submit(r)
+    with jax.profiler.trace(str(tmp_path)):
+        cs.drain(max_ticks=1000)
+    spans = _host_spans(tmp_path)
+    names = {s[2] for s in spans}
+    assert names == {"serve.tick", "serve.prefill"} | set(TICK_PHASES)
+    assert not names & set(SPAN_NAMES)
+    ticks = [s for s in spans if s[2] == "serve.tick"]
+    inner = [s for s in spans if s[2] != "serve.tick"]
+    assert sum(len(_inner_of(t, inner)) for t in ticks) == len(inner)
+    admitted = []
+    for tick in ticks:
+        top = _top_level(_inner_of(tick, inner))
+        order = [TICK_PHASES.index(s[2]) for s in top]
+        assert order == sorted(order), [s[2] for s in top]
+        assert [s[2] for s in top if s[2] != "serve.admit"] == \
+            list(TICK_PHASES[1:])
+        for adm in (s for s in top if s[2] == "serve.admit"):
+            admitted.append(adm[3]["request_id"])
+            assert [s[2] for s in _inner_of(adm, inner)] == list(ADMIT_PHASES)
+    assert sorted(admitted) == [r.request_id for r in reqs]
+
+
+def _inner_of(outer, spans):
+    return [s for s in spans if s is not outer and outer[0] <= s[0]
+            and s[1] <= outer[1]]
+
+
+def _top_level(spans):
+    return [s for s in spans
+            if not any(o is not s and o[0] <= s[0] and s[1] <= o[1]
+                       for o in spans)]
+
+
+# ---------------------------------------------------------------------------
 # Soak: Poisson arrivals under whatever site the CI matrix armed
 # ---------------------------------------------------------------------------
 

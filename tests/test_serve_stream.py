@@ -418,6 +418,6 @@ def test_serve_report_schema(engine, no_fault):
                                        "preempted", "resumed"}
     rec = next(iter(report["requests"].values()))
     assert set(rec) == {"status", "retries", "tokens_emitted", "latency_s",
-                        "events"}
+                        "queued_t", "admit_t", "first_token_t", "events"}
     assert rec["events"][0]["event"] == "admitted"
     assert rec["events"][-1]["event"] == "completed"
