@@ -62,12 +62,14 @@ def readings(spec, seeds, seconds, control, device, fault=None) -> None:
         from chipbench.faults import FAULTS
         FAULTS[fault](setattr)
     for seed in seeds:
-        t = time.perf_counter()
+        t, stats = time.perf_counter(), {}
         out = cell.run(spec, seed=seed, seconds=seconds, trace=False,
                        device=device, process_start=t, log=_quiet,
-                       trace_dir=TRACE_DIR, quantize=control)
-        print(_line(spec, seed, out, t, control=control, fault=fault),
-              flush=True)
+                       trace_dir=TRACE_DIR, quantize=control, stats=stats)
+        check = stats["check"]
+        print(_line(spec, seed, out, t, control=control, fault=fault,
+                    logit_err=check["logit_err"], tokens=check["tokens"],
+                    rows=sorted(stats["sample_rows"])), flush=True)
 
 
 def sustained(stats: dict) -> bool:
@@ -76,6 +78,9 @@ def sustained(stats: dict) -> bool:
 
 
 def sweep(spec, rates, seconds, seed, device, write_rate) -> None:
+    from chipbench.generator import closed_loop
+    if closed_loop(spec.traffic):
+        raise SystemExit(f"{spec.name}: a closed loop has no rate to sweep")
     best = None
     for rate in rates:
         s = dataclasses.replace(spec, traffic=dict(spec.traffic,

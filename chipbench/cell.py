@@ -178,25 +178,34 @@ def run(spec: CellSpec, *, seed: int, seconds: float, trace: bool, device,
     lead = float(mix["lead_in_s"])
     if tracer is not None:
         tracer.start = setup_end + lead + min(2.0, seconds / 4)
-    wlog = serve.run_window(server, arrivals, lead, seconds, on_tick=tracer)
+    closed = generator.closed_loop(mix)
+    wlog = serve.run_window(
+        server, arrivals, lead, seconds,
+        outstanding=serving["max_live"] + int(mix["backlog"]) if closed
+        else None, on_tick=tracer)
+    if closed:
+        sent = f"closed loop, {len(wlog.due)} requests submitted"
+    else:
+        late = np.asarray(wlog.late_s)
+        sent = (f"generator lateness: median {np.median(late) * 1e3:.3f} ms, "
+                f"max {late.max() * 1e3:.3f} ms over {late.size} arrivals")
     if tracer is not None:
         tracer.stop()
     in_window = compiles.count - built_before
     log(f"[window] programs built inside the lead-in, window and drain: "
         f"{in_window}")
-    late = np.asarray(wlog.late_s)
-    log(f"[window] generator lateness: median {np.median(late) * 1e3:.3f} ms, "
-        f"max {late.max() * 1e3:.3f} ms over {late.size} arrivals; drain "
-        f"{wlog.drained_at - wlog.end:.3f} s; queue at mid-window "
-        f"{wlog.queue_mid}, at the window's end {wlog.queue_end}")
+    log(f"[window] {sent}; drain {wlog.drained_at - wlog.end:.3f} s; queue at "
+        f"mid-window {wlog.queue_mid}, at the window's end {wlog.queue_end}")
     log(f"[window] dispatch {json.dumps(server.engine.dispatch_report)}; "
         f"degraded lowerings {json.dumps(server.engine.health_report())}")
     memory_peak = peak_bytes(device)
     results = server.sched.results
-    attempted = [a.request_id for a in arrivals]
+    # The requests submitted: every arrival of an open loop, those the
+    # closed loop sent before its window closed.
+    attempted = list(wlog.due)
     failed = [r for r in attempted
               if r not in results or results[r].status != "completed"]
-    ids = {a.request_id for a in arrivals}
+    ids = set(attempted)
     events = [(r, s, tt) for r, s, tt, _ in server.clock.events if r in ids]
     m = window.end_to_end(events, wlog.due, wlog.start, wlog.end)
     log(f"[window] {json.dumps(m)}")
@@ -206,9 +215,11 @@ def run(spec: CellSpec, *, seed: int, seconds: float, trace: bool, device,
                               if r.status == "shed"))
     served = {r: list(results[r].tokens) for r in attempted
               if r in results and results[r].status == "completed"}
-    prompts = {a.request_id: a.tokens for a in arrivals}
+    prompts = {a.request_id: a.tokens for a in arrivals
+               if a.request_id in ids}
+    rows = server.clock.rows
     sample = correct.sample_requests(served, seed,
-                                     int(mix["check"]["requests"]))
+                                     int(mix["check"]["requests"]), rows)
     probed = [server.clock.probed_logits(r, len(served[r])) for r in sample]
     traced = None
     if trace:
@@ -221,21 +232,21 @@ def run(spec: CellSpec, *, seed: int, seconds: float, trace: bool, device,
     got = correct.compare(arch, seed, [prompts[r] for r in sample],
                           [served[r] for r in sample], probed, probes,
                           serving["max_len"])
-    log(f"[check] reference over {len(sample)} requests, {got['tokens']} "
+    covered = {rows[r] for r in sample if r in rows}
+    log(f"[check] reference over {len(sample)} requests from "
+        f"{len(covered)} batch rows, "
+        f"{got['tokens']} "
         f"served tokens ({got['differ']} not the reference's greedy choice; "
         f"mean gap {got['mean_gap']:.6f}; widest gap "
         f"{got['widest_gap']:.6f}; largest logit error {got['logit_err']:.6f}; "
         f"logit rms error "
         f"{got['logit_rms_err']:.6f}) in {time.perf_counter() - t:.3f} s")
     if stats is not None:
-        stats["check"] = got
-    limits = spec.config["correct"]
-    compared = {
-        "mean_gap": {"value": got["mean_gap"],
-                     "limit": limits["mean_gap_limit"]},
-        "logit_rms_err": {"value": got["logit_rms_err"],
-                          "limit": limits["logit_rms_err_limit"]},
-        "compiles_in_window": {"value": in_window, "limit": 0}}
+        stats.update(check=got, sample_rows=covered)
+    compared = {key[:-len("_limit")]: {"value": got[key[:-len("_limit")]],
+                                       "limit": limit}
+                for key, limit in spec.config["correct"].items()}
+    compared["compiles_in_window"] = {"value": in_window, "limit": 0}
     ok = all(c["value"] <= c["limit"] for c in compared.values())
     metrics: Dict[str, dict] = {}
     if trace:

@@ -4,8 +4,8 @@ After the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and holding the request with the
 most served tokens, is run once through the plain float32 reference
 (``chipbench.reference``): each prompt followed by its served tokens, as one
-causal sequence. Two numbers are compared, over every position of the sample
-that produced a served token:
+causal sequence. The numbers, over every position of the sample that
+produced a served token:
 
 * ``mean_gap``: the reference's best logit minus its logit of the served
   token (0 where the served token is the reference's own greedy choice),
@@ -13,10 +13,13 @@ that produced a served token:
   program loses only what its bfloat16 arithmetic costs near ties: a flip
   happens where the reference's top two lie closer than the program's
   error, and costs at most that error, so the mean grows as the square of
-  the error. A token altered where it is produced costs the width of the
-  whole logit distribution. (The widest such gap, the largest single one,
-  is printed; it is not compared: at the cells' sizes the int8 control
-  reads less than three times what sound runs do.)
+  the error.
+* ``widest_gap``: the largest such gap. A sound flip costs at most the
+  program's error at that position; a token altered where it is produced
+  costs the width of the whole logit distribution, however many tokens
+  the sample holds, where the mean dilutes it. (At the cells' sizes the
+  int8 control reads less than three times what sound runs do here; it
+  fails the other numbers.)
 * ``logit_rms_err``: the root mean square of the gaps between the logits
   the timed path sampled from and the reference's, at the seed's probe ids
   (``probe_ids``), over the standard deviation of the reference's logits
@@ -24,6 +27,9 @@ that produced a served token:
   the ties it happens to flip, and is steady from seed to seed. (The
   largest such gap, ``logit_err``, is printed; it is not compared: in the
   prefill cell the int8 control reads 2.9 times what sound runs do.)
+
+A configuration's ``correct`` group names the limit of each number compared
+(``<number>_limit``).
 """
 from __future__ import annotations
 
@@ -40,17 +46,32 @@ def probe_ids(seed: int, vocab: int, k: int = PROBES) -> np.ndarray:
     return np.sort(rng.choice(vocab, size=k, replace=False)).astype(np.int32)
 
 
-def sample_requests(served: Dict[int, List[int]], seed: int, n: int) -> List[int]:
+def sample_requests(served: Dict[int, List[int]], seed: int, n: int,
+                    rows: Dict[int, int]) -> List[int]:
     """``n`` finished request ids: the one with the most served tokens
-    (lowest id on a tie), then others drawn from the seed."""
+    (lowest id on a tie), then one drawn from the seed for each batch row
+    (``rows``: request id -> the row its batched-step tokens came from) that
+    the sample does not cover yet, in an order drawn from the seed, then
+    others drawn from the seed. With ``n`` at least the batch's width, every
+    row the window used is checked: a fault confined to some rows cannot
+    fall outside the sample."""
     ids = sorted(served)
     if not ids:
         raise ValueError("no finished request to compare")
     longest = max(ids, key=lambda r: (len(served[r]), -r))
-    rest = [r for r in ids if r != longest]
     rng = np.random.default_rng(int(seed) + 1)
-    picked = rng.choice(len(rest), size=min(n - 1, len(rest)), replace=False)
-    return [longest] + sorted(rest[i] for i in picked)
+    picked = [longest]
+    open_rows = sorted({rows[r] for r in ids if r in rows} - {rows.get(longest)})
+    order = rng.permutation(len(open_rows)) if open_rows else []
+    for row in (open_rows[j] for j in order):
+        if len(picked) >= n:
+            break
+        here = [r for r in ids if rows.get(r) == row]
+        picked.append(here[int(rng.integers(len(here)))])
+    rest = [r for r in ids if r not in picked]
+    k = max(0, min(n - len(picked), len(rest)))
+    drawn = rng.choice(len(rest), size=k, replace=False)
+    return [longest] + sorted(picked[1:] + [rest[i] for i in drawn])
 
 
 def teacher_forced(prompts: Sequence[np.ndarray], served: Sequence[List[int]],

@@ -9,10 +9,10 @@ traffic mix (``chipbench/traffic/<traffic>.json``). Set-up builds the served
 weights from the seed on the device, builds the engine and scheduler, and
 serves one request of each prompt length so that every program of the
 window is compiled (or loaded from the persistent cache in
-``<checkout>/.jax_cache``) before it opens. Then open-loop traffic runs for a
-lead-in and the window, the requests in flight drain, peak device memory is
-read, the program's state is freed, and the float32 reference checks a
-sample of the served requests.
+``<checkout>/.jax_cache``) before it opens. Then the traffic (open loop, or
+a closed loop's backlog) runs for a lead-in and the window, the requests in
+flight drain, peak device memory is read, the program's state is freed, and
+the float32 reference checks a sample of the served requests.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` traces a
 few seconds of the window with the JAX profiler and reports its per-layer

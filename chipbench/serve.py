@@ -5,8 +5,9 @@
 benchmark builds the weights itself from the seed (``chipbench.weights``),
 lays them out as the program's parameter tree and packs them in the same
 jitted call, so no float32 or unpacked copy is ever resident. It then drives
-``ContinuousScheduler.step()`` in its own open loop and timestamps every
-token by wrapping the public ``sample_tokens`` of its own ``Engine``.
+``ContinuousScheduler.step()`` in its own loop (``run_window``, open or
+closed), and timestamps every token by wrapping the public
+``sample_tokens`` of its own ``Engine``.
 """
 from __future__ import annotations
 
@@ -113,7 +114,8 @@ class TokenClock:
     can compare the logits the timed path produced with the reference's.
     Once ``rows_in_use`` is set (a callable giving, for a batch as wide as
     its result, which rows hold a request), rows that do not are skipped:
-    they compute garbage under a live row's pair."""
+    they compute garbage under a live row's pair. ``rows`` maps each
+    request to the batch row its batched-step tokens were sampled in."""
 
     def __init__(self, engine, probe_ids, clock=time.perf_counter):
         from jax.profiler import TraceAnnotation
@@ -122,6 +124,7 @@ class TokenClock:
         self._seen = set()
         self._probes: list = []                  # device [width, K] per call
         self._probed: Dict[Tuple[int, int], Tuple[int, int]] = {}
+        self.rows: Dict[int, int] = {}
         self.rows_in_use = None
         ids = jnp.asarray(probe_ids, jnp.int32)
         take = jax.jit(lambda logits: logits[:, ids].astype(jnp.float32))
@@ -147,6 +150,8 @@ class TokenClock:
                 self._seen.add((r, s))
                 self.events.append((r, s, t, tok))
                 self._probed[(r, s)] = (len(self._probes), row)
+                if len(rids) > 1:               # the batched step's sampler
+                    self.rows.setdefault(r, row)
                 pairs.append((r, s))
             self._probes.append(probe)
             self.calls.append((t, int(logits.shape[0]), pairs))
@@ -253,30 +258,49 @@ class WindowLog:
     drained_at: float = 0.0
 
 
-def run_window(server: Server, arrivals, lead_in_s: float, seconds: float,
-               on_tick=None, clock=time.perf_counter,
-               sleep=time.sleep) -> WindowLog:
-    """Open loop: each arrival is submitted at the first tick after it is
-    due; after the window closes no new request arrives and the requests in
-    flight are drained, so each has a whole timeline."""
-    from jax.profiler import TraceAnnotation
+def _submit(sched, arrival) -> None:
     from repro.serve.requests import Request
+    sched.submit(Request(request_id=arrival.request_id, tokens=arrival.tokens,
+                         max_new_tokens=arrival.max_new_tokens))
+
+
+def run_window(server: Server, arrivals, lead_in_s: float, seconds: float,
+               outstanding: Optional[int] = None, on_tick=None,
+               clock=time.perf_counter, sleep=time.sleep) -> WindowLog:
+    """Serves ``arrivals`` for the lead-in and the window; after the window
+    closes no new request is submitted and the requests in flight are
+    drained, so each has a whole timeline.
+
+    Open loop (``outstanding`` None): each arrival is submitted at the first
+    tick after it is due. Closed loop: until the window closes, each tick
+    first tops the requests submitted and not finished up to
+    ``outstanding`` with the next arrivals, each due when submitted, so
+    ``due`` holds only the requests submitted."""
+    from jax.profiler import TraceAnnotation
     sched = server.sched
-    reqs = [Request(request_id=a.request_id, tokens=a.tokens,
-                    max_new_tokens=a.max_new_tokens) for a in arrivals]
     t0 = clock()
     log = WindowLog(start=t0 + lead_in_s, end=t0 + lead_in_s + seconds,
-                    due={a.request_id: t0 + a.due_s for a in arrivals},
-                    late_s=[], ticks=[])
+                    due={}, late_s=[], ticks=[])
+    if outstanding is None:
+        log.due = {a.request_id: t0 + a.due_s for a in arrivals}
     mid = log.start + seconds / 2
-    i, n = 0, len(reqs)
+    i, n = 0, len(arrivals)
     base = len(sched.results)
     while True:
         now = clock()
-        while i < n and t0 + arrivals[i].due_s <= now:
-            sched.submit(reqs[i])
-            log.late_s.append(now - (t0 + arrivals[i].due_s))
-            i += 1
+        if outstanding is None:
+            while i < n and t0 + arrivals[i].due_s <= now:
+                _submit(sched, arrivals[i])
+                log.late_s.append(now - (t0 + arrivals[i].due_s))
+                i += 1
+        elif now < log.end:
+            while i - (len(sched.results) - base) < outstanding:
+                if i >= n:
+                    raise RuntimeError(f"the closed loop's {n} requests ran "
+                                       f"out before the window closed")
+                _submit(sched, arrivals[i])
+                log.due[arrivals[i].request_id] = now
+                i += 1
         if log.queue_mid is None and now >= mid:
             log.queue_mid = sched.stats()["queued"]
         if log.queue_end is None and now >= log.end:
@@ -284,7 +308,7 @@ def run_window(server: Server, arrivals, lead_in_s: float, seconds: float,
         if on_tick is not None:
             on_tick(now)
         if i == len(sched.results) - base:      # nothing in flight
-            if i >= n:
+            if outstanding is not None or i >= n:
                 break
             sleep(max(0.0, t0 + arrivals[i].due_s - clock()))
             continue

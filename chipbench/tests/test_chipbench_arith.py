@@ -32,10 +32,14 @@ def test_generator_is_deterministic_per_seed(mix):
     assert [(x.due_s, x.tokens.size, x.max_new_tokens) for x in a] == \
         [(x.due_s, x.tokens.size, x.max_new_tokens) for x in c]
     assert not any(np.array_equal(x.tokens, y.tokens) for x, y in zip(a, c))
-    # The schedule is the mix's own: another schedule seed moves it.
+    # The schedule is the mix's own: another schedule seed moves it (a
+    # closed loop's requests are all due when sent: their order moves).
     d = generator.arrivals(dict(m, schedule_seed=m["schedule_seed"] + 1),
                            seed, 12.0, 50304)
-    assert [x.due_s for x in a] != [x.due_s for x in d]
+    if generator.closed_loop(m):
+        assert [x.max_new_tokens for x in a] != [x.max_new_tokens for x in d]
+    else:
+        assert [x.due_s for x in a] != [x.due_s for x in d]
 
 
 @pytest.mark.parametrize("mix", MIXES)
@@ -55,7 +59,11 @@ def test_generator_draws_only_table_lengths_and_same_work(mix):
     a, b = runs
     assert [(x.due_s, x.tokens.size, x.max_new_tokens) for x in a] == \
         [(x.due_s, x.tokens.size, x.max_new_tokens) for x in b]
-    n = round(m["rate_per_s"] * (m["lead_in_s"] + 12.0))
+    if generator.closed_loop(m):
+        n = generator.CLOSED_LOOP_REQUESTS
+        assert {x.due_s for x in a} == {0.0}
+    else:
+        n = round(m["rate_per_s"] * (m["lead_in_s"] + 12.0))
     assert len(a) == n
     table = m["output_len"]
     assert [sum(1 for x in a if x.max_new_tokens == v)

@@ -25,7 +25,14 @@ def test_cell_files_are_found_and_fit(name):
     assert generator.max_total_len(spec.traffic) <= serving["max_len"]
     assert serving["max_len"] % serving["block_size"] == 0
     assert serving["max_len"] <= spec.config["max_position_embeddings"]
-    assert spec.traffic["rate_per_s"] > 0 and spec.traffic["check"]["requests"] >= 1
+    if generator.closed_loop(spec.traffic):
+        assert spec.traffic["backlog"] >= 1
+    else:
+        assert spec.traffic["rate_per_s"] > 0
+    # The check compares a request from every batch row.
+    assert spec.traffic["check"]["requests"] >= serving["max_live"]
+    assert set(spec.config["correct"]) == {
+        "mean_gap_limit", "logit_rms_err_limit", "widest_gap_limit"}
     serve.program_config(spec.arch)      # the program runs this architecture
     e2e = {m["name"] for m in spec.end_to_end}
     assert "setup_s" in e2e and len(e2e) >= 2 and spec.per_layer
@@ -77,3 +84,28 @@ def test_entry_in_a_directory_of_only_the_benchmark_exits_nonzero(tmp_path):
     assert out.returncode != 0
     assert not any(line.lstrip().startswith("{")
                    for line in out.stdout.splitlines())
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_throughput_is_judged_only_where_the_loop_is_closed(name):
+    # In an open loop below its knee the tokens in the window are the
+    # schedule's (and the lead-in's backlog spilling into it), not the
+    # program's speed; only a closed loop's full batch reads capacity.
+    spec = cell.load(ROOT, name)
+    e2e = {m["name"] for m in spec.end_to_end}
+    assert ("tokens_per_s" in e2e) == generator.closed_loop(spec.traffic)
+
+
+def test_offline_cell_reports_its_metrics():
+    spec = cell.load(ROOT, "olmo1b-offline")
+    assert (spec.config_name, spec.traffic_name, spec.chips) == \
+        ("olmo-1b.live16-len1024", "backlog-olmo1b", 1)
+    assert {m["name"] for m in spec.end_to_end} == \
+        {"tokens_per_s", "tpot_ms", "itl_p95_ms", "setup_s"}
+    assert {m["name"] for m in spec.per_layer} == \
+        {"host_ms_per_tick", "device_idle_share", "mfu.decode",
+         "gemm_roofline.decode", "kv_view_ms.decode"}
+    # The lengths are the decode cell's: only the arrivals differ.
+    decode = cell.load(ROOT, "olmo1b-decode").traffic
+    for key in ("prompt_len", "output_len", "schedule_seed", "check"):
+        assert spec.traffic[key] == decode[key]

@@ -47,3 +47,13 @@ def test_control_is_not_correct():
     assert not control["correct"], control["compared"]
     assert control["compared"]["logit_rms_err"]["value"] > TINY_RMS_LIMIT
     assert control["compared"]["compiles_in_window"]["value"] == 0
+
+
+def test_control_is_not_correct_in_a_closed_loop():
+    seed = 2 ** 33 + 6
+    sound = run_tiny("olmo", seed=seed, width=128, layers=4, backlog=2)
+    control = run_tiny("olmo", seed=seed, width=128, layers=4, backlog=2,
+                       quantize="int8")
+    assert sound["correct"], sound["compared"]
+    assert not control["correct"], control["compared"]
+    assert control["compared"]["logit_rms_err"]["value"] > TINY_RMS_LIMIT
