@@ -7,7 +7,8 @@ same oracle).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import dataclasses
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -150,8 +151,24 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@dataclasses.dataclass(frozen=True)
+class PagedKV:
+    """A block pool of K/V, read one layer at a time by the batched decode.
+
+    ``read(layer)`` returns that layer's ``(k, v)``, each ``[B, max_len,
+    Hkv, D]`` in the compute dtype: every row's blocks in table order,
+    bitwise the dense cache of that row. ``layer`` is bound inside the
+    decode layer scan. Decoding through a ``PagedKV`` writes nothing back:
+    each layer returns only the position it wrote, for the caller to put
+    into the pool.
+    """
+
+    read: Callable[[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]]
+    layer: Optional[jnp.ndarray] = None
+
+
 def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
-                     cache: dict, pos: jnp.ndarray) -> Tuple[jnp.ndarray, dict]:
+                     cache, pos: jnp.ndarray) -> Tuple[jnp.ndarray, dict]:
     """One-token self attention. x: [B,1,d]; pos: [B] absolute position.
 
     The cache is a ring buffer of ``slots`` positions: slot s holds absolute
@@ -159,25 +176,31 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     congruent to s). Masking reconstructs absolute positions from slot ids, so
     sliding windows need no rolls — the paper's "packing" discipline applied
     to the KV stream: write once, contiguous layout, no data motion.
+
+    ``cache`` is a dense ``{"k", "v"}`` ring ``[B, slots, Hkv, D]``, returned
+    with the new position written; or a :class:`PagedKV` bound to a layer,
+    read here, for which only the written ``{"k", "v"}`` ``[B, Hkv, D]`` is
+    returned. Both put the new position in place by the same select, so a
+    paged row attends over exactly the values its dense cache would hold.
     """
     b = x.shape[0]
     window = cfg.sliding_window if cfg.attention_type == "sliding_window" else None
     q, k_new, v_new = project_qkv(cfg, p, x, pos[:, None])
-    slots = cache["k"].shape[1]
-    slot = (pos % slots)  # [B]
+    paged = isinstance(cache, PagedKV)
+    k_old, v_old = cache.read(cache.layer) if paged else (cache["k"],
+                                                          cache["v"])
+    k_new, v_new = k_new.astype(k_old.dtype), v_new.astype(v_old.dtype)
+    slots = k_old.shape[1]
+    slot_ids = jnp.arange(slots)[None, :]                      # [1, slots]
+    at = (slot_ids == (pos % slots)[:, None])[:, :, None, None]
 
     @jax.named_scope("kv_write")
     def write(buf, new):
-        onehot = jax.nn.one_hot(slot, slots, dtype=buf.dtype)  # [B, slots]
-        keep = 1.0 - onehot
-        return buf * keep[:, :, None, None] + new * onehot[:, :, None, None]
+        return jnp.where(at, new, buf)
 
-    k_cache = write(cache["k"], k_new.astype(cache["k"].dtype))
-    v_cache = write(cache["v"], v_new.astype(cache["v"].dtype))
-    k_cache = shard(k_cache, "batch", "kv_seq")
-    v_cache = shard(v_cache, "batch", "kv_seq")
+    k_cache = shard(write(k_old, k_new), "batch", "kv_seq")
+    v_cache = shard(write(v_old, v_new), "batch", "kv_seq")
 
-    slot_ids = jnp.arange(slots)[None, :]                      # [1, slots]
     posb = pos[:, None]
     k_positions = posb - ((posb - slot_ids) % slots)           # [B, slots]
     kv_valid = k_positions >= 0
@@ -190,4 +213,6 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                             kv_valid=kv_valid, chunk=1)
     out = out.reshape(b, 1, cfg.q_dim)
     out = gemm.linear(out, resolve_weight(p["wo"], x.dtype), p.get("bo"))
+    if paged:
+        return out, {"k": k_new[:, 0], "v": v_new[:, 0]}
     return out, {"k": k_cache, "v": v_cache}

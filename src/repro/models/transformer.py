@@ -4,6 +4,7 @@ for the 512-device dry-run; per-layer remat policy applied inside the scan).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
@@ -288,18 +289,28 @@ def decode_block(cfg: ModelConfig, p: dict, cache: dict, x: jnp.ndarray,
     return x, new_cache
 
 
-def decode(cfg: ModelConfig, params: dict, caches: dict, token: jnp.ndarray,
+def decode(cfg: ModelConfig, params: dict, caches, token: jnp.ndarray,
            pos: jnp.ndarray) -> Tuple[jnp.ndarray, dict]:
-    """token: [B,1]; pos: [B] -> (logits [B,1,V], new caches)."""
+    """token: [B,1]; pos: [B] -> (logits [B,1,V], new caches).
+
+    ``caches`` is the stacked per-layer dense caches, or an
+    :class:`~repro.models.attention.PagedKV` that each layer reads its own
+    K/V from; then the new caches are only the written positions,
+    ``{"kv": {"k", "v"}}`` of ``[L, B, Hkv, D]``.
+    """
     compute = jnp.dtype(cfg.compute_dtype)
     x = embed_tokens(cfg, params, token, compute)
+    paged = isinstance(caches, attn.PagedKV)
 
     def scan_fn(carry, layer_in):
         lp, lc = layer_in
+        if paged:
+            lc = {"kv": dataclasses.replace(caches, layer=lc)}
         new_x, new_c = decode_block(cfg, lp, lc, carry, pos)
         return new_x, new_c
 
+    per_layer = jnp.arange(cfg.num_layers) if paged else caches
     x, new_caches = jax.lax.scan(
-        scan_fn, x, (cast_layer_params(cfg, params["layers"]), caches))
+        scan_fn, x, (cast_layer_params(cfg, params["layers"]), per_layer))
     x = apply_norm(cfg, params["final_norm"], x)
     return lm_logits(cfg, params, x), new_caches

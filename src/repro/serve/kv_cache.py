@@ -30,10 +30,11 @@ Block-accounting contract
   parked in a recycled block would otherwise leak through the masked value
   contraction (0 · NaN = NaN). After a full drain
   ``allocator.free_count == allocator.capacity`` (property-swept in tests).
-* ``max_len % block_size == 0`` is required so a fully-tabled slot gathers to
-  EXACTLY the dense ``max_len`` cache the batch-1 programs use — the gathered
-  view and the dense cache are then the same ring arithmetic, which is what
-  makes the batched step bitwise-equal to the batch-1 path (the bisection and
+* ``max_len % block_size == 0`` is required so a fully-tabled slot reads as
+  EXACTLY the dense ``max_len`` cache the batch-1 programs use — the batched
+  step's per-layer read of a row's blocks and the dense cache are then the
+  same ring arithmetic, written by the same select, which is what makes the
+  batched step bitwise-equal to the batch-1 path (the bisection and
   preempt-resume contracts ride on this).
 
 Supported families: decoder-only token LMs with full attention (dense / moe /
@@ -73,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import PagedKV
 from repro.testing import faults
 
 # The two paged leaves of a decoder-only attention cache.
@@ -89,7 +91,11 @@ def _scatter_blocks(pool, row, blocks):
     return pool.at[:, row].set(blocks)
 
 
-@jax.jit
+# The scrubs write the pool in place (donated): release runs for every
+# finished slot of a tick before anything waits on the chip, and a copying
+# scrub per slot and leaf would keep that many whole pools alive at once.
+
+@functools.partial(jax.jit, donate_argnums=0)
 def _scrub_row(pool, row):
     zeros = jnp.zeros((pool.shape[0], row.shape[0], *pool.shape[2:]),
                       pool.dtype)
@@ -140,7 +146,7 @@ def _scatter_blocks_q(pool, scales, row, leaf):
     return pool.at[:, row].set(qb), scales.at[:, row].set(sb)
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scrub_row_q(pool, scales, row):
     zeros = jnp.zeros((pool.shape[0], row.shape[0], *pool.shape[2:]),
                       pool.dtype)
@@ -163,6 +169,29 @@ def _write_pos_q(pool, scales, dest, written):
     sflat = scales.reshape(scales.shape[0], -1)
     return (flat.at[:, dest].set(q).reshape(pool.shape),
             sflat.at[:, dest].set(s).reshape(scales.shape))
+
+
+def pool_view(pool_k, pool_v, tables, scale_k=None, scale_v=None, *,
+              dtype=None) -> PagedKV:
+    """The batched decode step's read of the pool, one layer at a time.
+
+    Inside the layer scan, ``read(layer)`` gathers that layer's blocks of
+    every row by ``tables`` straight from the pool (one gather indexed by
+    the layer number, so no per-layer pool slice is written) into ``[B,
+    max_len, Hkv, D]``; a quantized pool dequantizes them elementwise into
+    ``dtype``, as ``gather_slot`` does, so each row reads bitwise its dense
+    batch-1 cache."""
+    def leaf(pool, scales, layer):
+        g = pool[layer, tables]                  # [B, MB, bs, Hkv, D]
+        if scales is not None:
+            g = dequantize_kv(g, scales[layer, tables], dtype)
+        return g.reshape(tables.shape[0], -1, *pool.shape[3:])
+
+    @jax.named_scope("kv_gather")
+    def read(layer):
+        return leaf(pool_k, scale_k, layer), leaf(pool_v, scale_v, layer)
+
+    return PagedKV(read)
 
 
 class BlockAllocator:
@@ -218,10 +247,11 @@ class PagedKVCache:
     ``pool[name]: [L, num_blocks + 1, block_size, Hkv, D]`` for ``name`` in
     ``("k", "v")`` — plus a HOST block table ``tables: [max_live,
     blocks_per_slot] int32`` mapping each slot's position range onto pool
-    blocks (0 = null block). The batched decode step gathers
-    ``pool[:, tables]`` into the dense ``[L, B, max_len, Hkv, D]`` view the
-    unchanged model ``decode`` consumes, and scatters back only the one
-    position each row wrote.
+    blocks (0 = null block). The batched decode step reads the pool one
+    layer at a time inside the model's layer scan (:func:`pool_view`: that
+    layer's blocks of every row, by ``tables``) and scatters back only the
+    one position each row wrote, ``[L, B, Hkv, D]``; no dense ``[L, B,
+    max_len, Hkv, D]`` view of all layers is built.
 
     ``quantize="int8"`` stores the pool as int8 values + per-position f32
     scale leaves (see the module docstring's quantized-pool contract);
@@ -240,7 +270,7 @@ class PagedKVCache:
                 f"{model_cfg.attention_type!r} not pageable)")
         if max_len % block_size != 0:
             raise ValueError(f"max_len={max_len} must be a multiple of "
-                             f"block_size={block_size} (gathered view must "
+                             f"block_size={block_size} (a slot's blocks must "
                              "equal the dense batch-1 cache exactly)")
         if quantize not in (None, "int8"):
             raise ValueError(
@@ -390,7 +420,7 @@ class PagedKVCache:
         bitwise the cache the batch-1 programs would hold (bisection re-runs
         and tests read through this). Quantized pools dequantize into the
         compute dtype — elementwise ``q * scale``, so the view is bitwise
-        the batched step's gathered operand per row."""
+        what the batched step's per-layer read gives that row."""
         row = jnp.asarray(self.tables[slot])
         if self.quantize:
             dt = self.compute_dtype.name

@@ -50,7 +50,8 @@ phases in order: ``serve.admit`` per admission (argument ``request_id``;
 it holds ``serve.prefill`` and the first token's ``serve.token_wait`` and
 ``serve.commit``), ``serve.kv_grow``, ``serve.step_dispatch``,
 ``serve.token_wait`` (the host blocked on the chip) and ``serve.commit``.
-The batched step names its KV traffic ``kv_gather`` and ``kv_scatter``
+The batched step names its KV traffic ``kv_gather`` (each layer's read of
+its blocks) and ``kv_scatter`` (the written positions put into the pool)
 (``jax.named_scope``).
 """
 from __future__ import annotations
@@ -161,72 +162,46 @@ class ContinuousScheduler:
     # ----- the shared batched decode program ------------------------------
 
     def _build_step(self):
-        """One jit'd program for the whole batch, compiled ONCE: gather each
-        row's blocks into the dense ``[L, B, max_len, Hkv, D]`` view the
-        unchanged model ``decode`` consumes, run it, and scatter back only
-        the single position each row wrote. Dead rows (all-null tables,
-        token 0, pos 0) compute identical garbage and land their write in
-        the null block — masked everywhere, bitwise inert.
+        """One jit'd program for the whole batch, compiled ONCE. Inside the
+        model's layer scan each layer reads its rows' blocks straight from
+        the pool (``kv_cache.pool_view``, scope ``kv_gather``), puts the new
+        position in place by a select and attends; the scan emits only the
+        written positions ``[L, B, Hkv, D]``, and one scatter per leaf
+        (``kv_scatter``) writes them into a new pool. No dense ``[L, B,
+        max_len, Hkv, D]`` view is built. Dead rows (all-null tables, token
+        0, pos 0) compute identical garbage and land their write in the null
+        block — masked everywhere, bitwise inert.
 
         A quantized pool (``cfg.kv_quantize``) threads the per-position
-        scale leaves through the same program: the gather dequantizes
-        ``q * scale`` into the compute dtype (elementwise, so each row's
-        dense view is bitwise ``gather_slot``'s), and the scatter quantizes
-        the one written position with the shared
-        ``kv_cache.quantize_kv_position`` formula — the same bytes a batch-1
-        ``write_position`` of that vector would commit."""
-        from repro.serve.kv_cache import dequantize_kv, quantize_kv_position
+        scale leaves through the same program: the read dequantizes ``q *
+        scale`` into the compute dtype (elementwise, so each row reads
+        bitwise ``gather_slot``'s view), and the scatter quantizes the
+        written positions with the shared ``kv_cache.quantize_kv_position``
+        formula — the same bytes a batch-1 ``write_position`` of that vector
+        would commit."""
+        from repro.serve.kv_cache import _write_pos, _write_pos_q, pool_view
         model = self.engine.model
         B = self.cfg.max_live
-        max_len = self.kv.max_len
         bs = self.kv.block_size
         compute_dtype = self.kv.compute_dtype.name
 
         def step(params, pool_k, pool_v, tables, tokens, pos):
-            def gather(pool):
-                g = pool[:, tables]          # [L, B, MB, bs, Hkv, D]
-                return g.reshape(g.shape[0], B, max_len, *g.shape[4:])
-
-            with jax.named_scope("kv_gather"):
-                caches = {"kv": {"k": gather(pool_k), "v": gather(pool_v)}}
-            logits, new = model.decode(params, caches, tokens, pos)
+            logits, new = model.decode(
+                params, pool_view(pool_k, pool_v, tables), tokens, pos)
             dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
-
-            @jax.named_scope("kv_scatter")
-            def scatter(pool, leaf):
-                idx = pos[None, :, None, None, None]
-                written = jnp.take_along_axis(leaf, idx, axis=2)[:, :, 0]
-                flat = pool.reshape(pool.shape[0], -1, *pool.shape[3:])
-                return flat.at[:, dest].set(written).reshape(pool.shape)
-
-            return (logits[:, 0], scatter(pool_k, new["kv"]["k"]),
-                    scatter(pool_v, new["kv"]["v"]))
+            with jax.named_scope("kv_scatter"):
+                return (logits[:, 0], _write_pos(pool_k, dest, new["kv"]["k"]),
+                        _write_pos(pool_v, dest, new["kv"]["v"]))
 
         def step_q(params, pool_k, pool_v, scale_k, scale_v, tables,
                    tokens, pos):
-            def gather(pool, scales):
-                g = dequantize_kv(pool[:, tables], scales[:, tables],
-                                  compute_dtype)
-                return g.reshape(g.shape[0], B, max_len, *g.shape[4:])
-
-            with jax.named_scope("kv_gather"):
-                caches = {"kv": {"k": gather(pool_k, scale_k),
-                                 "v": gather(pool_v, scale_v)}}
-            logits, new = model.decode(params, caches, tokens, pos)
+            view = pool_view(pool_k, pool_v, tables, scale_k, scale_v,
+                             dtype=compute_dtype)
+            logits, new = model.decode(params, view, tokens, pos)
             dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
-
-            @jax.named_scope("kv_scatter")
-            def scatter(pool, scales, leaf):
-                idx = pos[None, :, None, None, None]
-                written = jnp.take_along_axis(leaf, idx, axis=2)[:, :, 0]
-                q, s = quantize_kv_position(written)     # [L, B(, h, d)]
-                flat = pool.reshape(pool.shape[0], -1, *pool.shape[3:])
-                sflat = scales.reshape(scales.shape[0], -1)
-                return (flat.at[:, dest].set(q).reshape(pool.shape),
-                        sflat.at[:, dest].set(s).reshape(scales.shape))
-
-            pk, sk = scatter(pool_k, scale_k, new["kv"]["k"])
-            pv, sv = scatter(pool_v, scale_v, new["kv"]["v"])
+            with jax.named_scope("kv_scatter"):
+                pk, sk = _write_pos_q(pool_k, scale_k, dest, new["kv"]["k"])
+                pv, sv = _write_pos_q(pool_v, scale_v, dest, new["kv"]["v"])
             return logits[:, 0], pk, pv, sk, sv
 
         return jax.jit(step_q if self.cfg.kv_quantize else step)

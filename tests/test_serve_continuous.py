@@ -177,6 +177,22 @@ def test_paged_cache_rejects_unpageable_shapes(engine):
         PagedKVCache(swa, max_live=2, max_len=32, block_size=8, num_blocks=8)
 
 
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_release_scrubs_the_pool_in_place(engine, quantize):
+    """Release writes the pool in place: the scrubbed pool replaces the one
+    it was given (donated), so releasing several slots in a tick holds one
+    pool per leaf, not one per release."""
+    kv = PagedKVCache(engine.model.cfg, max_live=2, max_len=32, block_size=8,
+                      num_blocks=8, quantize=quantize)
+    assert kv.grow(0, 6) and kv.grow(1, 6)
+    before = dict(kv.pool)
+    kv.release(0)
+    kv.release(1)
+    assert all(before[name].is_deleted() for name in before)
+    assert np.all(np.asarray(kv.pool["k"]) == 0)
+    assert kv.alloc.free_count == kv.alloc.capacity
+
+
 # ---------------------------------------------------------------------------
 # Bitwise parity with the batch-1 front-end
 # ---------------------------------------------------------------------------
@@ -192,6 +208,69 @@ def test_continuous_matches_batch1_bitwise(engine, no_fault):
     assert s["completed"] == 8 and s["preempted"] == 0
     for rid, toks in ref.items():
         np.testing.assert_array_equal(cs.results[rid].tokens, toks)
+
+
+@pytest.mark.parametrize("arch", ["qwen3-4b", "llama4-scout-17b-a16e",
+                                  "command-r-plus-104b"])
+def test_paged_step_matches_batch1_bitwise_per_family(arch, no_fault):
+    """Each family the pool pages (dense with qk-norm and GQA, MoE,
+    parallel-block) runs the batched step's per-layer paged read through
+    its own ``decode_block`` path, bitwise the batch-1 front-end."""
+    cfg = dataclasses.replace(reduced_config(arch), compute_dtype="float32",
+                              capacity_factor=16.0)
+    model = build(cfg)
+    eng = Engine(model, model.init(jax.random.PRNGKey(0)),
+                 ServeConfig(max_len=32, temperature=0.7, seed=3))
+    ref = _batch1_reference(eng, _requests(6, seed=4))
+    cs = _serve_all(eng, _requests(6, seed=4))
+    assert _assert_conservation(cs, 6)["completed"] == 6
+    for rid, toks in ref.items():
+        np.testing.assert_array_equal(cs.results[rid].tokens, toks)
+
+
+def _avals(jaxpr):
+    """Every value's abstract shape in a jaxpr and the jaxprs it holds,
+    with each ``scan``'s outputs apart."""
+    vals, scan_outs = [], []
+    for eqn in jaxpr.eqns:
+        vals += [v.aval for v in eqn.outvars]
+        if eqn.primitive.name == "scan":
+            scan_outs += [v.aval for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            v, so = _avals(sub)
+            vals += v
+            scan_outs += so
+    return vals, scan_outs
+
+
+@pytest.mark.parametrize("kv_quantize", [None, "int8"])
+def test_batched_step_builds_no_dense_view(engine, no_fault, kv_quantize):
+    """The batched step reads each layer's blocks inside the layer scan and
+    emits only the written positions: no value of the dense ``[L, B,
+    max_len, Hkv, D]`` view (in any layout, nor its int8 scales ``[L, B,
+    max_len]``) is built, and the scan's outputs hold no ``max_len`` axis.
+    The sizes make the view's element count differ from the pool's."""
+    import jax.numpy as jnp
+    max_len = 48
+    eng = Engine(engine.model, engine.params, ServeConfig(max_len=max_len))
+    cs, _ = _sched(eng, num_kv_blocks=9, kv_quantize=kv_quantize)
+    mc, kv, B = eng.model.cfg, cs.kv, cs.cfg.max_live
+    L, hkv, d = mc.num_layers, mc.num_kv_heads, mc.head_dim
+    assert kv.pool["k"].size != L * B * max_len * hkv * d
+    scales = [kv.scales["k"], kv.scales["v"]] if kv_quantize else []
+    args = [eng.params, kv.pool["k"], kv.pool["v"], *scales,
+            kv.device_tables(), jnp.zeros((B, 1), jnp.int32),
+            jnp.zeros((B,), jnp.int32)]
+    vals, scan_outs = _avals(jax.make_jaxpr(cs._jit_step)(*args).jaxpr)
+    sizes = {int(np.prod(a.shape)) for a in vals if hasattr(a, "shape")}
+    assert L * B * max_len * hkv * d not in sizes
+    assert L * B * max_len not in sizes
+    assert scan_outs and all(max_len not in a.shape for a in scan_outs)
+    # the same step, run, is still the batched decode: the pool it returns
+    # holds each row's written position
+    out = cs._jit_step(*args)
+    assert out[0].shape == (B, mc.vocab_size)
+    assert not np.array_equal(np.asarray(out[1]), np.asarray(kv.pool["k"]))
 
 
 # ---------------------------------------------------------------------------
