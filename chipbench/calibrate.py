@@ -21,7 +21,8 @@ end no longer than at its middle); ``--write-rate f`` then writes ``f``
 times the highest sustained rate into the cell's traffic file. ``record``
 makes one traced run and keeps its reduced trace (and the raw profile when
 it is small) in ``--out``. ``--traffic '{...}'`` overrides keys of the mix
-and ``--config <name>`` serves another configuration file under it.
+and ``--config <name>`` serves another configuration file (with the
+architecture module it names) under it.
 """
 from __future__ import annotations
 
@@ -139,10 +140,9 @@ def main(argv=None) -> int:
     spec = dataclasses.replace(spec, traffic=dict(spec.traffic,
                                                   **json.loads(args.traffic)))
     if args.config:
-        with open(os.path.join(cell.HERE, "configs",
-                               args.config + ".json")) as f:
-            spec = dataclasses.replace(spec, config_name=args.config,
-                                       config=json.load(f))
+        config, model = cell.read_config(ROOT, args.config)
+        spec = dataclasses.replace(spec, config_name=args.config,
+                                   config=config, model=model)
     device = require_chips(spec.chips)[0]
     enable_cache(ROOT)
     if args.mode == "readings":

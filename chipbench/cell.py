@@ -2,8 +2,10 @@
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; each
 is a file of its own (``configs/<config>.json``, ``traffic/<traffic>.json``),
-and each per-layer metric a reader of its own (``metrics/<metric>.py``). A
-new cell or metric is new files and entries: nothing here names one.
+each per-layer metric a reader of its own (``metrics/<metric>.py``), and
+each configuration its architecture, a module of its own
+(``arch/<arch>.py``, by the configuration's key ``"arch"``). A new cell,
+metric or architecture is new files and entries: nothing here names one.
 """
 from __future__ import annotations
 
@@ -12,6 +14,7 @@ import gc
 import importlib.util
 import json
 import os
+import re
 import shutil
 import time
 from typing import Callable, Dict, List, Optional
@@ -29,32 +32,60 @@ class CellSpec:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    model: object          # the configuration's architecture module
 
     @property
     def arch(self) -> dict:
-        return arch_of(self.config)
+        return self.model.arch_of(self.config)
 
     @property
     def serving(self) -> dict:
         return self.config["serving"]
 
 
-def arch_of(config: dict) -> dict:
-    """What the program and the reference need of a configuration file:
-    its sizes, ``norm`` (the reference's ``layernorm`` or ``rmsnorm``) and
-    ``program_norm_type`` (the same norm by the program's name)."""
-    keys = ("registry", "hidden_size", "intermediate_size",
-            "num_hidden_layers", "num_attention_heads", "num_key_value_heads",
-            "vocab_size", "tie_word_embeddings", "rope_theta", "norm",
-            "program_norm_type")
-    arch = {k: config[k] for k in keys}
-    arch["head_dim"] = config["hidden_size"] // config["num_attention_heads"]
-    return arch
-
-
 def _read_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def _module(mod_name: str, path: str):
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+# A configuration's architecture: a file name under ``arch/``.
+ARCH_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]*")
+_ARCHITECTURES: Dict[str, object] = {}   # loaded modules, by file
+
+
+def architecture(config: dict, path: str):
+    """The architecture module a configuration file names with its key
+    ``"arch"``: ``arch/<arch>.py`` beside the file's ``configs/`` (``path``:
+    the configuration file), loaded once per process. A configuration that
+    names none that exists stops the run with a message naming the file."""
+    name = config.get("arch")
+    home = os.path.dirname(os.path.dirname(os.path.abspath(path)))
+    file = os.path.join(home, "arch", f"{name}.py")
+    if not isinstance(name, str) or not ARCH_NAME.fullmatch(name) \
+            or not os.path.isfile(file):
+        raise SystemExit(f"chipbench: the configuration {path} names no "
+                         f"architecture module: its \"arch\" is {name!r}, "
+                         f"and there is no {file}")
+    if file not in _ARCHITECTURES:
+        _ARCHITECTURES[file] = _module(
+            "chipbench_arch_" + name.replace(".", "_").replace("-", "_"),
+            file)
+    return _ARCHITECTURES[file]
+
+
+def read_config(root: str, name: str) -> tuple:
+    """The configuration ``chipbench/configs/<name>.json`` under ``root``,
+    and its architecture module."""
+    path = os.path.join(root, "chipbench", "configs", name + ".json")
+    config = _read_json(path)
+    return config, architecture(config, path)
 
 
 def metrics_for(bench: dict, cell: str) -> tuple:
@@ -74,23 +105,20 @@ def load(root: str, workload: str) -> CellSpec:
                          f"BENCHMARK.json")
     w = matches[0]
     e2e, layers = metrics_for(bench, workload)
+    config, model = read_config(root, w["config"])
     return CellSpec(
         name=workload, chips=int(w["chips"]), config_name=w["config"],
-        traffic_name=w["traffic"],
-        config=_read_json(os.path.join(HERE, "configs", w["config"] + ".json")),
-        traffic=_read_json(os.path.join(HERE, "traffic",
+        traffic_name=w["traffic"], config=config,
+        traffic=_read_json(os.path.join(root, "chipbench", "traffic",
                                         w["traffic"] + ".json")),
-        end_to_end=e2e, per_layer=layers)
+        end_to_end=e2e, per_layer=layers, model=model)
 
 
 def metric_reader(name: str):
     """The reader module ``metrics/<name>.py``."""
-    path = os.path.join(HERE, "metrics", name + ".py")
-    mod_name = "chipbench_metric_" + name.replace(".", "_").replace("-", "_")
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module(
+        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"),
+        os.path.join(HERE, "metrics", name + ".py"))
 
 
 def _device_info(device) -> dict:
@@ -159,7 +187,8 @@ def run(spec: CellSpec, *, seed: int, seconds: float, trace: bool, device,
     compiles = serve.CompileCounter()
     t = time.perf_counter()
     probes = correct.probe_ids(seed, arch["vocab_size"])
-    server = serve.build_server(arch, serving, seed, probes, quantize)
+    server = serve.build_server(spec.model, arch, serving, seed, probes,
+                                quantize)
     log(f"[setup] weights built and packed on the device in "
         f"{time.perf_counter() - t:.3f} s")
     t = time.perf_counter()
@@ -229,7 +258,8 @@ def run(spec: CellSpec, *, seed: int, seconds: float, trace: bool, device,
     del server
     gc.collect()
     t = time.perf_counter()
-    got = correct.compare(arch, seed, [prompts[r] for r in sample],
+    got = correct.compare(spec.model, arch, seed,
+                          [prompts[r] for r in sample],
                           [served[r] for r in sample], probed, probes,
                           serving["max_len"])
     covered = {rows[r] for r in sample if r in rows}
@@ -275,7 +305,8 @@ def _traced_metrics(spec, server, wlog, tracer, device, trace_dir, prompts,
     if keep_trace:
         T.save_context(keep_trace, reduced, server.clock.calls, wlog.ticks,
                        (tracer.t_on, tracer.t_off), prompt_len, trace_dir)
-    ctx = T.Context(trace=reduced, arch=spec.arch, serving=spec.serving,
+    ctx = T.Context(trace=reduced, model=spec.model, arch=spec.arch,
+                    serving=spec.serving,
                     peaks=peaks(device.device_kind), prompt_len=prompt_len,
                     calls=server.clock.calls, ticks=wlog.ticks,
                     host_window=(tracer.t_on, tracer.t_off))

@@ -2,10 +2,10 @@
 
 After the window has closed and the program's state is freed, a sample of
 the finished requests, drawn from the seed and holding the request with the
-most served tokens, is run once through the plain float32 reference
-(``chipbench.reference``): each prompt followed by its served tokens, as one
-causal sequence. The numbers, over every position of the sample that
-produced a served token:
+most served tokens, is run once through the plain float32 reference (the
+architecture module's ``token_gaps``, ``chipbench/arch/<name>.py``): each
+prompt followed by its served tokens, as one causal sequence. The numbers,
+over every position of the sample that produced a served token:
 
 * ``mean_gap``: the reference's best logit minus its logit of the served
   token (0 where the served token is the reference's own greedy choice),
@@ -95,14 +95,14 @@ def teacher_forced(prompts: Sequence[np.ndarray], served: Sequence[List[int]],
     return tokens, targets, mask
 
 
-def compare(arch: dict, seed: int, prompts, served, probed, ids,
+def compare(model, arch: dict, seed: int, prompts, served, probed, ids,
             length: int) -> dict:
-    """Run the reference over the sample and return both numbers and the
-    counts. ``probed[i]``: [len(served[i]), K] logits the program sampled
-    request i's tokens from, at vocabulary ids ``ids``."""
-    from chipbench.reference.dense import token_gaps
+    """Run the reference of the architecture module ``model`` over the
+    sample and return the numbers and the counts. ``probed[i]``:
+    [len(served[i]), K] logits the program sampled request i's tokens from,
+    at vocabulary ids ``ids``."""
     tokens, targets, mask = teacher_forced(prompts, served, length)
-    gaps, best, ref = token_gaps(arch, seed, tokens, targets, ids)
+    gaps, best, ref = model.token_gaps(arch, seed, tokens, targets, ids)
     gaps, best, ref = np.asarray(gaps), np.asarray(best), np.asarray(ref)
     got = np.zeros_like(ref)
     for i, (p, rows) in enumerate(zip(prompts, probed)):

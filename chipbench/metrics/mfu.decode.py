@@ -2,19 +2,17 @@
 step's share of the chip's bf16 peak.
 
 Model operations of the tokens the traced batched steps produced for live
-rows (weights, and attention over each row's real context; from
-``chipbench.flops``), over the device time of those steps' programs times
-the peak. Padding rows do no useful work and count nothing. Moves
-``tpot_ms``.
+rows (weights, and attention over each row's real context; the
+architecture's ``decode_token_flops``), over the device time of those
+steps' programs times the peak. Padding rows do no useful work and count
+nothing. Moves ``tpot_ms``.
 """
-from chipbench import flops
-
 UNIT, LAYER, MOVES = "%", "model step", "tpot_ms"
 
 
 def read(ctx):
     steps = ctx.step_modules()
-    ops = sum(flops.decode_token_flops(ctx.arch, ctx.prompt_len[r] + s - 1)
+    ops = sum(ctx.model.decode_token_flops(ctx.arch, ctx.prompt_len[r] + s - 1)
               for _, pairs in steps for r, s in pairs if s >= 1)
     device_s = sum(m[1] - m[0] for m, _ in steps) / 1e9
     if ops == 0 or device_s <= 0:

@@ -260,32 +260,22 @@ def result_shape(text: str) -> Tuple[int, ...]:
     return tuple(int(x) for x in m.group(1).split(",") if x) if m else ()
 
 
-def kv_elements(arch: dict, serving: dict) -> Tuple[int, int]:
-    """Elements of one leaf of the paged KV pool ``[L, blocks + 1, block,
-    Hkv, D]`` and of the dense view ``[L, max_live, max_len, Hkv, D]`` the
-    batched step gathers from it."""
-    per_position = (arch["num_hidden_layers"] * arch["num_key_value_heads"]
-                    * arch["head_dim"])
-    blocks = serving["max_live"] * serving["max_len"] // serving["block_size"]
-    return ((blocks + 1) * serving["block_size"] * per_position,
-            serving["max_live"] * serving["max_len"] * per_position)
-
-
 def step_scope_ms(ctx, scopes: List[str]) -> Optional[Dict[str, float]]:
     """Device ms per traced batched step under each named scope, and
     ``kv_view``: the ops scoped ``kv_gather``, ``kv_write`` or
     ``kv_scatter``, with the ops of no named scope that produce a whole KV
-    buffer, the pool or the dense view in any layout (the pool's copies,
-    and the scan's stacking of the blended cache, which carries the scan's
-    ``op_name``). An op with no scope of its own takes that of the op
-    holding it (a gather loop's body takes the loop's); ops that hold
-    others are left out, so no time counts twice. Each is the union of
-    its ops' intervals. None when no op of the steps has a KV scope.
+    buffer, the pool or the dense view in any layout (their sizes are the
+    architecture's ``kv_elements``: the pool's copies, and the scan's
+    stacking of the blended cache, which carries the scan's ``op_name``).
+    An op with no scope of its own takes that of the op holding it (a
+    gather loop's body takes the loop's); ops that hold others are left
+    out, so no time counts twice. Each is the union of its ops' intervals.
+    None when no op of the steps has a KV scope.
     ``scopes``: aligned with ``ctx.trace.ops``."""
     steps = [m for m, _ in ctx.step_modules()]
     if not steps or len(scopes) != len(ctx.trace.ops):
         return None
-    kv_sizes = set(kv_elements(ctx.arch, ctx.serving))
+    kv_sizes = set(ctx.model.kv_elements(ctx.arch, ctx.serving))
     starts = [o[0] for o in ctx.trace.ops]
     found = False
     ivs: Dict[str, list] = {k: [] for k in SCOPES + ("kv_view",)}

@@ -3,10 +3,11 @@
 ``repro``'s ``Engine`` over packed bfloat16 weights, behind its
 ``ContinuousScheduler`` (paged KV pool, one batched decode program). The
 benchmark builds the weights itself from the seed (``chipbench.weights``),
-lays them out as the program's parameter tree and packs them in the same
-jitted call, so no float32 or unpacked copy is ever resident. It then drives
-``ContinuousScheduler.step()`` in its own loop (``run_window``, open or
-closed), and timestamps every token by wrapping the public
+lays them out as the program's parameter tree (the configuration's
+architecture module, ``chipbench/arch/<name>.py``) and packs them in the
+same jitted call, so no float32 or unpacked copy is ever resident. It then
+drives ``ContinuousScheduler.step()`` in its own loop (``run_window``, open
+or closed), and timestamps every token by wrapping the public
 ``sample_tokens`` of its own ``Engine``.
 """
 from __future__ import annotations
@@ -22,65 +23,12 @@ import numpy as np
 from chipbench import weights as W
 
 
-def program_config(arch: dict):
-    """The program's ``ModelConfig``: the registry entry with every size
-    taken from the benchmark's configuration file."""
-    from repro.configs import get_config
-    base = get_config(arch["registry"])
-    cfg = dataclasses.replace(
-        base, num_layers=arch["num_hidden_layers"],
-        d_model=arch["hidden_size"], num_heads=arch["num_attention_heads"],
-        num_kv_heads=arch["num_key_value_heads"], head_dim=arch["head_dim"],
-        d_ff=arch["intermediate_size"], vocab_size=arch["vocab_size"],
-        tie_embeddings=arch["tie_word_embeddings"],
-        rope_theta=arch["rope_theta"])
-    want = {"norm_type": arch["program_norm_type"], "mlp_type": "swiglu",
-            "family": "dense", "attention_type": "full", "use_bias": False,
-            "qk_norm": False, "parallel_block": False,
-            "pos_embedding": "rope", "compute_dtype": "bfloat16"}
-    got = {k: getattr(cfg, k) for k in want}
-    if got != want:
-        raise ValueError(f"{arch['registry']}: program config {got} is not "
-                         f"the architecture the reference computes {want}")
-    return cfg
-
-
-def _param_tree(key, arch: dict) -> dict:
-    """The program's parameter layout, filled from the benchmark's leaves."""
-    L, d = arch["num_hidden_layers"], arch["hidden_size"]
-    shapes = W.layer_shapes(arch)
-
-    def st(name):
-        return W.stacked(key, name, shapes[name], L)
-
-    def norm(name):
-        if not W.parametric_norm(arch):
-            return {}
-        return {"scale": W.stacked(key, name, (d,), L).astype(jnp.float32)}
-
-    g = W.global_weights(key, arch)
-    params = {
-        "embed": {"table": g["embed"]},
-        "layers": {
-            "norm1": norm("norm1"),
-            "attn": {"wq": st("wq"), "wk": st("wk"), "wv": st("wv"),
-                     "wo": st("wo")},
-            "norm2": norm("norm2"),
-            "mlp": {"wg": st("w_gate"), "wu": st("w_up"), "wo": st("w_down")},
-        },
-        "final_norm": ({"scale": g["final_norm"].astype(jnp.float32)}
-                       if "final_norm" in g else {}),
-    }
-    if "head" in g:
-        params["head"] = {"table": g["head"]}
-    return params
-
-
-def check_layout(model, arch: dict) -> None:
-    """The benchmark's tree must have exactly the program's structure and
-    shapes (its dtypes are the served ones, so they may differ)."""
-    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    got = jax.eval_shape(lambda k: _param_tree(k, arch),
+def check_layout(model, program, arch: dict) -> None:
+    """The benchmark's tree (``model``, the architecture module, lays it
+    out) must have exactly the ``program``'s structure and shapes (its
+    dtypes are the served ones, so they may differ)."""
+    want = jax.eval_shape(program.init, jax.random.PRNGKey(0))
+    got = jax.eval_shape(lambda k: model.param_tree(k, arch),
                          jax.random.PRNGKey(0))
     ws = jax.tree.map(lambda a: a.shape, want)
     gs = jax.tree.map(lambda a: a.shape, got)
@@ -89,15 +37,17 @@ def check_layout(model, arch: dict) -> None:
                          f"{gs} vs {ws}")
 
 
-def build_params(model, arch: dict, seed: int, quantize: Optional[str] = None):
-    """Served weights on the device in one jitted call: draw, lay out, pack
+def build_params(model, program, arch: dict, seed: int,
+                 quantize: Optional[str] = None):
+    """Served weights of the ``program`` on the device in one jitted call:
+    draw and lay out (``model``, the architecture module), pack
     (``quantize`` switches on the program's own int8 weight path)."""
     from repro.models.layers import pack_model_params
-    check_layout(model, arch)
+    check_layout(model, program, arch)
 
     @jax.jit
     def make(key):
-        return pack_model_params(model.cfg, _param_tree(key, arch),
+        return pack_model_params(program.cfg, model.param_tree(key, arch),
                                  quantize=quantize)
 
     params = make(W.root_key(seed))
@@ -208,14 +158,16 @@ class Server:
     clock: TokenClock
 
 
-def build_server(arch: dict, serving: dict, seed: int, probe_ids,
+def build_server(model, arch: dict, serving: dict, seed: int, probe_ids,
                  quantize: Optional[str] = None) -> Server:
+    """The engine and scheduler over the served weights of the program that
+    the architecture module ``model`` configures."""
     from repro.models import build
     from repro.serve.engine import Engine, ServeConfig
     from repro.serve.scheduler import ContinuousConfig, ContinuousScheduler
-    model = build(program_config(arch))
-    params = build_params(model, arch, seed, quantize)
-    engine = Engine(model, params, ServeConfig(
+    program = build(model.program_config(arch))
+    params = build_params(model, program, arch, seed, quantize)
+    engine = Engine(program, params, ServeConfig(
         max_len=serving["max_len"], temperature=0.0,
         cache_dtype=serving["cache_dtype"], pack_weights=False))
     clock = TokenClock(engine, probe_ids)

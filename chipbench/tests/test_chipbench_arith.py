@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from chipbench import flops, generator, window
+from chipbench.arch import dense
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(HERE, "traffic"))
@@ -123,15 +124,15 @@ def test_flops_of_one_olmo_gemm_by_hand():
 
 
 def test_flops_of_the_olmo_step():
-    gemms = flops.weight_gemms(OLMO, 16)
+    gemms = dense.weight_gemms(OLMO, 16)
     assert len(gemms) == 7 * 16 + 1 and gemms[-1] == (16, 2048, 50304)
     per_layer = 4 * 2048 * 2048 + 3 * 2048 * 8192
-    assert flops.layer_params(OLMO) == 16 * per_layer
-    assert flops.matmul_params(OLMO) == 16 * per_layer + 2048 * 50304
-    assert flops.decode_token_flops(OLMO, 99) == \
-        2 * flops.matmul_params(OLMO) + 4 * 16 * 2048 * 100
+    assert dense.layer_params(OLMO) == 16 * per_layer
+    assert dense.matmul_params(OLMO) == 16 * per_layer + 2048 * 50304
+    assert dense.decode_token_flops(OLMO, 99) == \
+        2 * dense.matmul_params(OLMO) + 4 * 16 * 2048 * 100
     n = 1024
-    assert flops.prefill_flops(OLMO, n) == (
+    assert dense.prefill_flops(OLMO, n) == (
         2 * 16 * per_layer * n + 2 * 2048 * 50304
         + 4 * 16 * 2048 * n * (n + 1) // 2)
-    assert flops.weight_gemms(OLMO, n, head_rows=1)[-1] == (1, 2048, 50304)
+    assert dense.weight_gemms(OLMO, n, head_rows=1)[-1] == (1, 2048, 50304)

@@ -9,7 +9,7 @@ import sys
 
 import pytest
 
-from chipbench import cell, generator, serve
+from chipbench import cell, generator
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(HERE)
@@ -33,7 +33,7 @@ def test_cell_files_are_found_and_fit(name):
     assert spec.traffic["check"]["requests"] >= serving["max_live"]
     assert set(spec.config["correct"]) == {
         "mean_gap_limit", "logit_rms_err_limit", "widest_gap_limit"}
-    serve.program_config(spec.arch)      # the program runs this architecture
+    spec.model.program_config(spec.arch)  # the program runs this architecture
     e2e = {m["name"] for m in spec.end_to_end}
     assert "setup_s" in e2e and len(e2e) >= 2 and spec.per_layer
     for m in spec.per_layer:

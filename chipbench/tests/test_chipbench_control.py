@@ -19,8 +19,9 @@ from chipbench.tests.tinycell import TINY_RMS_LIMIT, run_tiny, tiny_spec
 def test_control_serves_int8_weights():
     spec = tiny_spec("olmo")
     from repro.models import build
-    model = build(serve.program_config(spec.arch))
-    params = serve.build_params(model, spec.arch, 3, quantize="int8")
+    program = build(spec.model.program_config(spec.arch))
+    params = serve.build_params(spec.model, program, spec.arch, 3,
+                                quantize="int8")
     packed = params["layers"]["mlp"]["wg"]
     assert packed.packed.dtype == jnp.int8 and packed.scales is not None
 

@@ -16,7 +16,7 @@ import pytest
 
 from chipbench import serve
 from chipbench import weights as W
-from chipbench.reference import dense
+from chipbench.arch import dense
 from chipbench.tests.tinycell import tiny_spec
 
 
@@ -36,11 +36,11 @@ SEED = 2 ** 32 + 17
 def test_reference_agrees_with_the_program_model_in_float32(model_type):
     from repro.models import build
     arch = tiny_arch(model_type)
-    cfg = dataclasses.replace(serve.program_config(arch),
+    cfg = dataclasses.replace(dense.program_config(arch),
                               compute_dtype="float32")
     model = build(cfg)
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          serve._param_tree(W.root_key(SEED), arch))
+                          dense.param_tree(W.root_key(SEED), arch))
     tokens = np.random.default_rng(0).integers(0, 256, (2, 24)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         got, _ = model.forward(params, {"tokens": jnp.asarray(tokens)},
@@ -54,9 +54,9 @@ def test_reference_agrees_with_the_program_model_in_float32(model_type):
 def test_layer_draws_equal_the_stacked_draw():
     arch = tiny_arch("phi3")
     key = W.root_key(SEED)
-    tree = jax.jit(lambda k: serve._param_tree(k, arch))(key)
+    tree = jax.jit(lambda k: dense.param_tree(k, arch))(key)
     for layer in range(arch["num_hidden_layers"]):
-        one = W.layer_weights(key, arch, layer)
+        one = dense.layer_weights(key, arch, layer)
         assert np.array_equal(np.asarray(tree["layers"]["attn"]["wq"][layer]),
                               np.asarray(one["wq"]))
         assert np.array_equal(np.asarray(tree["layers"]["mlp"]["wo"][layer]),
@@ -67,8 +67,8 @@ def test_layer_draws_equal_the_stacked_draw():
 
 
 def test_seeds_beyond_32_bits_draw_different_weights():
-    a = W.leaf(W.root_key(5), "wq", (8, 8))
-    b = W.leaf(W.root_key(5 + 2 ** 32), "wq", (8, 8))
+    a = dense.leaf(W.root_key(5), "wq", (8, 8))
+    b = dense.leaf(W.root_key(5 + 2 ** 32), "wq", (8, 8))
     assert not np.array_equal(np.asarray(a), np.asarray(b))
     assert a.dtype == jnp.bfloat16
 
@@ -78,11 +78,11 @@ def test_served_tree_serves_what_engine_packing_serves(model_type):
     from repro.models import build
     from repro.serve.engine import Engine, ServeConfig
     arch = tiny_arch(model_type)
-    model = build(serve.program_config(arch))
-    raw = jax.jit(lambda k: serve._param_tree(k, arch))(W.root_key(SEED))
-    ours = Engine(model, serve.build_params(model, arch, SEED),
+    program = build(dense.program_config(arch))
+    raw = jax.jit(lambda k: dense.param_tree(k, arch))(W.root_key(SEED))
+    ours = Engine(program, serve.build_params(dense, program, arch, SEED),
                   ServeConfig(max_len=32, pack_weights=False))
-    theirs = Engine(model, raw, ServeConfig(max_len=32, pack_weights=True))
+    theirs = Engine(program, raw, ServeConfig(max_len=32, pack_weights=True))
     batch = {"tokens": jnp.asarray(np.random.default_rng(1).integers(
         0, 256, (2, 12)).astype(np.int32))}
     a = ours.generate(batch, max_new_tokens=6)

@@ -10,6 +10,7 @@ import os
 import pytest
 
 from chipbench import cell, program as P, trace as T
+from chipbench.arch import dense
 from chipbench.cell import metric_reader
 from chipbench.peaks import peaks
 
@@ -143,9 +144,10 @@ def _known(tmp_path, **kw):
     path = tmp_path / "t.xplane.pb"
     path.write_bytes(_xspace(**kw))
     reduced = T.reduce_profile(ProfileData.from_file(str(path)))
-    ctx = T.Context(trace=reduced, arch=ARCH, serving=SERVING, peaks=PEAKS,
-                    prompt_len={7: 2}, calls=[(1.011, 2, [(7, 1)])],
-                    ticks=[(1.0, 1.012)], host_window=(0.9, 1.1))
+    ctx = T.Context(trace=reduced, model=dense, arch=ARCH, serving=SERVING,
+                    peaks=PEAKS, prompt_len={7: 2},
+                    calls=[(1.011, 2, [(7, 1)])], ticks=[(1.0, 1.012)],
+                    host_window=(0.9, 1.1))
     return str(path), ctx
 
 
@@ -170,7 +172,7 @@ def test_op_scopes_are_read_from_the_event_metadata(tmp_path):
 
 def test_kv_view_and_the_step_scopes(tmp_path, capsys):
     path, ctx = _known(tmp_path)
-    assert P.kv_elements(ARCH, SERVING) == (5 * 2 * 8, 2 * 4 * 8)
+    assert dense.kv_elements(ARCH, SERVING) == (5 * 2 * 8, 2 * 4 * 8)
     prog = P.load(ctx, str(tmp_path))
     ms = P.step_scope_ms(ctx, prog.op_scopes)
     # The loop's body takes its scope (2 ms; the loop itself is left out);
@@ -215,9 +217,9 @@ def test_queue_wait_reads_the_program_request_times():
              8: {"queued_t": 0.5, "admit_t": 0.5, "first_token_t": 0.6},
              9: {"queued_t": 0.6, "admit_t": None, "first_token_t": None},
              10: {"queued_t": 0.9, "admit_t": 1.5, "first_token_t": 1.6}}
-    ctx = T.Context(trace=None, arch=ARCH, serving=SERVING, peaks=PEAKS,
-                    prompt_len={7: 2, 8: 2, 9: 2, 10: 2}, calls=[], ticks=[],
-                    host_window=(0, 1))
+    ctx = T.Context(trace=None, model=dense, arch=ARCH, serving=SERVING,
+                    peaks=PEAKS, prompt_len={7: 2, 8: 2, 9: 2, 10: 2},
+                    calls=[], ticks=[], host_window=(0, 1))
     # Two requests admitted before the profiler stopped (at 1 s) waited 250
     # and 0 ms; the third never was admitted, the fourth only after.
     assert read(ctx, times) == pytest.approx(250)
@@ -245,7 +247,8 @@ def test_queue_wait_reads_the_program_request_times():
 def _recorded(name, stem=""):
     spec = cell.load(ROOT, name)
     return T.load_context(os.path.join(DATA, name + stem + ".trace.json.gz"),
-                          spec.arch, spec.serving, peaks("TPU v5 lite"))
+                          spec.model, spec.arch, spec.serving,
+                          peaks("TPU v5 lite"))
 
 
 def _program(name):
@@ -316,7 +319,8 @@ def test_recorded_ticks_spans_scopes_and_request_times(name):
     pins = RECORDED[name]
     assert ms["kv_view"] == pytest.approx(pins["kv_view_ms.decode"], rel=1e-9)
     if "queue_wait_p95_ms" in pins:
-        ctx_q = T.Context(trace=ctx.trace, arch=ctx.arch, serving=ctx.serving,
+        ctx_q = T.Context(trace=ctx.trace, model=ctx.model, arch=ctx.arch,
+                          serving=ctx.serving,
                           peaks=ctx.peaks, prompt_len=ctx.prompt_len,
                           calls=ctx.calls, ticks=ctx.ticks,
                           host_window=ctx.host_window)

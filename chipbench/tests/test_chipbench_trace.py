@@ -7,6 +7,7 @@ import os
 import pytest
 
 from chipbench import cell, flops, trace as T
+from chipbench.arch import dense
 from chipbench.cell import metric_reader
 from chipbench.peaks import peaks
 
@@ -63,7 +64,7 @@ def _context():
     calls = [(host0 + 0.0045, 1, [(7, 0)]),               # first token of 7
              (host0 + 0.011, 4, [(7, 1), (3, 5)]),        # step 1: two rows
              (host0 + 0.0165, 4, [(7, 2), (3, 6)])]       # step 2
-    return T.Context(trace=reduced, arch=ARCH,
+    return T.Context(trace=reduced, model=dense, arch=ARCH,
                      serving={"max_live": 4}, peaks=PEAKS,
                      prompt_len={7: 64, 3: 128},
                      calls=calls, ticks=ticks,
@@ -106,13 +107,14 @@ def test_readers_on_the_known_trace():
     assert read["host_ms_per_tick"] == pytest.approx((5 + 2) / 2)
     assert read["device_idle_share"] == pytest.approx(55.0)
     positions = [64 + 1 - 1, 128 + 5 - 1, 64 + 2 - 1, 128 + 6 - 1]
-    ops = sum(flops.decode_token_flops(ARCH, p) for p in positions)
+    ops = sum(dense.decode_token_flops(ARCH, p) for p in positions)
     assert read["mfu.decode"] == pytest.approx(100 * ops / (0.005 * 197e12))
     assert read["mfu.prefill"] == pytest.approx(
-        100 * flops.prefill_flops(ARCH, 64) / (0.003 * 197e12))
-    ideal = flops.step_gemm_ideal_s(ARCH, 4, 197e12, 819e9)
+        100 * dense.prefill_flops(ARCH, 64) / (0.003 * 197e12))
+    ideal = flops.step_gemm_ideal_s(dense.weight_gemms(ARCH, 4), 197e12, 819e9)
     assert read["gemm_roofline.decode"] == pytest.approx(100 * 2 * ideal / 0.004)
-    ideal = flops.step_gemm_ideal_s(ARCH, 64, 197e12, 819e9, head_rows=1)
+    ideal = flops.step_gemm_ideal_s(dense.weight_gemms(ARCH, 64, head_rows=1),
+                                    197e12, 819e9)
     assert read["gemm_roofline.prefill"] == pytest.approx(100 * ideal / 0.002)
 
 
@@ -125,7 +127,8 @@ def _recorded():
     spec = cell.load(ROOT, "olmo1b-prefill")
     return T.load_context(os.path.join(HERE, "data",
                                        "olmo1b-prefill.trace.json.gz"),
-                          spec.arch, spec.serving, peaks("TPU v5 lite"))
+                          spec.model, spec.arch, spec.serving,
+                          peaks("TPU v5 lite"))
 
 
 def test_readers_on_a_recorded_chip_trace():
@@ -161,5 +164,6 @@ def test_gemm_kernels_alone_leave_out_their_weights_fetch():
     ops = ctx.program_ops(step)
     kernels_s = sum(o[1] - o[0] for o in ops
                     if T.gemm_weight_operand(o, shapes) is not None) / 1e9
-    ideal = flops.step_gemm_ideal_s(ctx.arch, 8, 197e12, 819e9)
+    ideal = flops.step_gemm_ideal_s(ctx.model.weight_gemms(ctx.arch, 8),
+                                    197e12, 819e9)
     assert kernels_s < ideal < ctx.gemm_time_s([step])

@@ -1,5 +1,6 @@
 """A cell at a size the CPU runs in seconds, for the harness's tests: the
 real configuration files with every size cut, and a short mix."""
+import dataclasses
 import json
 import os
 
@@ -29,8 +30,18 @@ def tiny_spec(model_type: str = "olmo", width: int = 64,
     outstanding, measured with the metrics of ``olmo1b-offline``; without
     it the mix is open-loop, with those of ``olmo1b-prefill``. The check
     compares ``requests`` of the finished requests."""
-    with open(os.path.join(HERE, "configs", CONFIGS[model_type] + ".json")) as f:
-        config = json.load(f)
+    config, model = cell.read_config(ROOT, CONFIGS[model_type])
+    return cut(cell.CellSpec(name="tiny", chips=1, config_name="tiny",
+                             traffic_name="tiny", config=config, traffic={},
+                             end_to_end=[], per_layer=[], model=model),
+               width, layers, backlog, requests)
+
+
+def cut(spec: cell.CellSpec, width: int = 64, layers: int = 2,
+        backlog: int = 0, requests: int = 100) -> cell.CellSpec:
+    """``spec`` at ``tiny_spec``'s sizes, mix and metrics (its name and
+    architecture module kept)."""
+    config = json.loads(json.dumps(spec.config))
     config.update(hidden_size=width, intermediate_size=2 * width,
                   num_hidden_layers=layers, num_attention_heads=width // 16,
                   num_key_value_heads=width // 16, vocab_size=8192)
@@ -49,20 +60,23 @@ def tiny_spec(model_type: str = "olmo", width: int = 64,
         bench = json.load(f)
     e2e, layers = cell.metrics_for(
         bench, "olmo1b-offline" if backlog else "olmo1b-prefill")
-    return cell.CellSpec(name="tiny", chips=1, config_name="tiny",
-                         traffic_name="tiny", config=config, traffic=mix,
-                         end_to_end=e2e, per_layer=layers)
+    return dataclasses.replace(spec, traffic_name="tiny", config=config,
+                               traffic=mix, end_to_end=e2e, per_layer=layers)
 
 
 def run_tiny(model_type: str = "olmo", seed: int = 2 ** 31 + 11,
              width: int = 64, layers: int = 2, backlog: int = 0,
              requests: int = 100, **kw) -> dict:
+    return run_spec(tiny_spec(model_type, width, layers, backlog, requests),
+                    seed, **kw)
+
+
+def run_spec(spec: cell.CellSpec, seed: int, **kw) -> dict:
+    """One run of a cut cell on the CPU, with a window of one second."""
     import time
     import jax
-    return cell.run(tiny_spec(model_type, width, layers, backlog, requests),
-                    seed=seed,
-                    seconds=1.0,
-                    trace=False, device=jax.devices()[0],
+    return cell.run(spec, seed=seed, seconds=1.0, trace=False,
+                    device=jax.devices()[0],
                     process_start=time.perf_counter(), log=lambda m: None,
                     trace_dir=os.path.join(ROOT, ".chipbench", "test-trace"),
                     **kw)

@@ -125,12 +125,12 @@ def save_context(path: str, reduced: Reduced, calls, ticks, host_window,
                 shutil.copyfileobj(src, dst)
 
 
-def load_context(path: str, arch: dict, serving: dict, peaks: dict):
+def load_context(path: str, model, arch: dict, serving: dict, peaks: dict):
     """A ``Context`` from a file ``save_context`` wrote."""
     with gzip.open(path, "rt") as f:
         d = json.load(f)
-    return Context(trace=Reduced.from_json(d["reduced"]), arch=arch,
-                   serving=serving, peaks=peaks,
+    return Context(trace=Reduced.from_json(d["reduced"]), model=model,
+                   arch=arch, serving=serving, peaks=peaks,
                    prompt_len={int(r): n for r, n in d["prompt_len"].items()},
                    calls=[(t, w, [tuple(p) for p in pairs])
                           for t, w, pairs in d["calls"]],
@@ -258,13 +258,6 @@ def operands(text: str) -> List[Tuple[str, Tuple[int, ...], str]]:
             for d, dims, name in _OPERAND.findall(args)]
 
 
-def weight_shapes(arch: dict) -> List[Tuple[int, int]]:
-    """``(K, N)`` of every weight GEMM of the model (the LM head's too)."""
-    from chipbench.weights import layer_shapes
-    return list(layer_shapes(arch).values()) + [
-        (arch["hidden_size"], arch["vocab_size"])]
-
-
 def gemm_weight_operand(op, shapes) -> Optional[str]:
     """For a packed-weight GEMM kernel, the name of its weight operand; else
     None. Such a kernel is a ``tpu_custom_call`` one of whose operands is a
@@ -350,8 +343,11 @@ class Tick:
 
 @dataclasses.dataclass
 class Context:
-    """What a per-layer metric's reader gets."""
+    """What a per-layer metric's reader gets. ``model`` is the cell's
+    architecture module (``chipbench/arch/<name>.py``) and ``arch`` what its
+    ``arch_of`` read from the configuration."""
     trace: Reduced
+    model: object
     arch: dict
     serving: dict
     peaks: dict
@@ -364,7 +360,7 @@ class Context:
         return [s for s in self.trace.spans if s[2] == "sched.step"]
 
     def gemm_shapes(self) -> List[Tuple[int, int]]:
-        return weight_shapes(self.arch)
+        return self.model.weight_shapes(self.arch)
 
     def program_ops(self, module: Interval) -> list:
         return inside(self.trace.ops, [module])
