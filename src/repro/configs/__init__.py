@@ -1,6 +1,8 @@
 """Config registry: ``get_config(arch_id)`` / ``all_configs()``.
 
-Arch ids match the assignment table exactly (``--arch <id>``).
+``ARCH_IDS`` / ``all_configs()`` are the assignment table's archs exactly
+(``--arch <id>``); ``get_config`` also finds the configurations served
+beyond it (the on-chip benchmark's).
 """
 from __future__ import annotations
 
@@ -19,26 +21,29 @@ from repro.configs.whisper_base import CONFIG as _whisper
 from repro.configs.paligemma_3b import CONFIG as _paligemma
 from repro.configs.hymba_1p5b import CONFIG as _hymba
 from repro.configs.mamba2_130m import CONFIG as _mamba2
+from repro.configs.granite_4_0_h_micro import CONFIG as _granite_h_micro
 
-_REGISTRY: Dict[str, ModelConfig] = {
+_ASSIGNED: Dict[str, ModelConfig] = {
     cfg.name: cfg
     for cfg in (
         _command_r_plus, _phi3, _qwen3, _olmo, _mixtral,
         _llama4, _whisper, _paligemma, _hymba, _mamba2,
     )
 }
+_SERVED: Dict[str, ModelConfig] = {cfg.name: cfg for cfg in (_granite_h_micro,)}
+_REGISTRY: Dict[str, ModelConfig] = {**_ASSIGNED, **_SERVED}
 
-ARCH_IDS: List[str] = list(_REGISTRY)
+ARCH_IDS: List[str] = list(_ASSIGNED)
 
 
 def get_config(arch: str) -> ModelConfig:
     if arch not in _REGISTRY:
-        raise KeyError(f"unknown arch {arch!r}; available: {ARCH_IDS}")
+        raise KeyError(f"unknown arch {arch!r}; available: {list(_REGISTRY)}")
     return _REGISTRY[arch]
 
 
 def all_configs() -> List[ModelConfig]:
-    return list(_REGISTRY.values())
+    return list(_ASSIGNED.values())
 
 
 def reduced_config(arch: str) -> ModelConfig:
@@ -73,4 +78,6 @@ def reduced_config(arch: str) -> ModelConfig:
         encoder_seq=24 if cfg.is_encoder_decoder else 0,
         num_patches=8 if cfg.num_patches else 0,
     )
+    if cfg.layer_typed:   # one layer of each kind, attention first
+        changes.update(layer_types=("attention", "mamba"))
     return dataclasses.replace(cfg, **changes)

@@ -7,7 +7,10 @@ dry-run launcher before XLA_FLAGS are finalized.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
+
+# Mixer kinds of a layer-typed stack (``ModelConfig.layer_types``).
+MAMBA, ATTENTION = "mamba", "attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -15,6 +18,11 @@ class ModelConfig:
     """Architecture description covering all assigned families.
 
     Families: dense | moe | audio (enc-dec) | vlm | hybrid (attn+ssm) | ssm.
+
+    A ``hybrid`` with ``layer_types`` stacks layers of two kinds in a fixed
+    order (Granite-4.0-H): each layer has ONE mixer, ``"mamba"`` or
+    ``"attention"``, and every layer an MLP. Without ``layer_types`` a
+    hybrid runs both mixers in every layer (Hymba's parallel heads).
     """
 
     name: str
@@ -43,6 +51,19 @@ class ModelConfig:
     attention_type: str = "full"        # full | sliding_window | none
     sliding_window: Optional[int] = None
     parallel_block: bool = False        # x + attn(h) + mlp(h) (Cohere-style)
+
+    # Layer-typed stacks: the mixer of each layer, in order (None: every
+    # layer has the family's mixers). Each attention layer is followed by a
+    # mamba layer (the stack runs as mamba layers, each after the attention
+    # layer, if any, that precedes it).
+    layer_types: Optional[Tuple[str, ...]] = None
+    # Scalings (Granite): the softmax scale (None: 1/sqrt(head_dim)), the
+    # embedding's, each sublayer output's before the residual add, and the
+    # divisor of the logits. Each default leaves the program as it was.
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # Mixture of experts.
     num_experts: int = 0
@@ -82,6 +103,22 @@ class ModelConfig:
             raise ValueError(f"{self.name}: moe family needs num_experts > 0")
         if self.family == "ssm" and self.ssm_state_size <= 0:
             raise ValueError(f"{self.name}: ssm family needs ssm_state_size > 0")
+        if self.layer_types is not None:
+            kinds = tuple(self.layer_types)
+            if len(kinds) != self.num_layers or not set(kinds) <= {
+                    MAMBA, ATTENTION}:
+                raise ValueError(
+                    f"{self.name}: layer_types must give {self.num_layers} "
+                    f"kinds of {MAMBA!r} or {ATTENTION!r}, got {kinds}")
+            if self.family != "hybrid" or not (self.has_ssm
+                                               and self.num_heads):
+                raise ValueError(f"{self.name}: layer_types needs a hybrid "
+                                 "with attention heads and ssm state")
+            if any(a == ATTENTION and b != MAMBA
+                   for a, b in zip(kinds, kinds[1:] + ("end",))):
+                raise ValueError(f"{self.name}: in layer_types each "
+                                 "attention layer must be followed by a "
+                                 f"mamba layer, got {kinds}")
 
     # ---- Derived quantities -------------------------------------------------
 
@@ -111,6 +148,23 @@ class ModelConfig:
         return self.ssm_state_size > 0
 
     @property
+    def layer_typed(self) -> bool:
+        """Layers of two kinds, one mixer each (``layer_types``)."""
+        return self.layer_types is not None
+
+    @property
+    def num_attention_layers(self) -> int:
+        if self.layer_typed:
+            return self.layer_types.count(ATTENTION)
+        return self.num_layers if self.has_attention else 0
+
+    @property
+    def num_ssm_layers(self) -> int:
+        if self.layer_typed:
+            return self.layer_types.count(MAMBA)
+        return self.num_layers if self.has_ssm else 0
+
+    @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
@@ -124,19 +178,24 @@ class ModelConfig:
         return self.attention_type == "sliding_window"
 
     def num_params(self) -> int:
-        """Analytic parameter count (used for MODEL_FLOPS = 6·N·D)."""
+        """Analytic parameter count (used for MODEL_FLOPS = 6·N·D): each
+        layer's mixers (attention, SSM, or one of them by ``layer_types``),
+        its MLP and norms, and the embedding and head."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        per_layer = 0
+        attn = 0
         if self.has_attention:
-            per_layer += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+            attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
             if self.qk_norm:
-                per_layer += 2 * self.head_dim
+                attn += 2 * self.head_dim
+        ssm = 0
         if self.has_ssm:
             di, ns, nh = self.d_inner, self.ssm_state_size, self.ssm_num_heads
             # in_proj -> [z, x, B, C, dt] ; out_proj
-            per_layer += d * (2 * di + 2 * ns + nh) + di * d
-            per_layer += self.ssm_conv_width * (di + 2 * ns)  # conv over x,B,C
-            per_layer += 2 * nh + di  # A_log, dt_bias, D (skip) params
+            ssm += d * (2 * di + 2 * ns + nh) + di * d
+            ssm += self.ssm_conv_width * (di + 2 * ns)  # conv over x,B,C
+            ssm += di + 2 * ns                 # conv bias
+            ssm += 2 * nh + nh + di  # A_log, dt_bias, D (skip), gated norm
+        per_layer = 0
         if f > 0:
             ff_in = 2 * d * f if self.mlp_type in ("swiglu", "geglu") else d * f
             ff = ff_in + f * d
@@ -147,7 +206,8 @@ class ModelConfig:
         # norms (rmsnorm scale only; nonparametric has none)
         nrm = d if self.norm_type != "nonparametric_ln" else 0
         per_layer += 2 * nrm
-        total = self.num_layers * per_layer
+        total = (self.num_layers * per_layer + self.num_attention_layers * attn
+                 + self.num_ssm_layers * ssm)
         total += v * d  # embedding
         if not self.tie_embeddings:
             total += v * d  # lm head

@@ -82,7 +82,8 @@ def self_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     window = cfg.sliding_window if cfg.attention_type == "sliding_window" else None
     q, k, v = project_qkv(cfg, p, x, positions)
     out = chunked_attention(q, k, v, causal=causal, window=window,
-                            prefix_len=prefix_len)
+                            prefix_len=prefix_len,
+                            scale=cfg.attention_multiplier)
     out = out.reshape(*x.shape[:-1], cfg.q_dim)
     heads_ax = "model" if cfg.shard_attention else None
     out = shard(out, "batch", None, heads_ax)
@@ -210,7 +211,8 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     out = chunked_attention(q, k_cache, v_cache, causal=True,
                             q_positions=pos[:, None],
                             k_positions=k_positions,
-                            kv_valid=kv_valid, chunk=1)
+                            kv_valid=kv_valid, chunk=1,
+                            scale=cfg.attention_multiplier)
     out = out.reshape(b, 1, cfg.q_dim)
     out = gemm.linear(out, resolve_weight(p["wo"], x.dtype), p.get("bo"))
     if paged:

@@ -271,6 +271,8 @@ def embed_tokens(cfg: ModelConfig, params: dict, tokens: jnp.ndarray,
     x = table[tokens]
     if cfg.family == "vlm":  # gemma-style scaled embeddings
         x = x * jnp.asarray(math.sqrt(cfg.d_model), compute_dtype)
+    if cfg.embedding_multiplier != 1.0:   # Granite's scaled embeddings
+        x = x * jnp.asarray(cfg.embedding_multiplier, compute_dtype)
     return shard(x, "batch")
 
 
@@ -289,8 +291,10 @@ def lm_logits(cfg: ModelConfig, params: dict, x: jnp.ndarray) -> jnp.ndarray:
         # the ("batch", None, "model") layout pinned below.
         head = shard(table.T.astype(x.dtype), None, "model")
     # logits keep a full-precision cross-shard reduce (softmax sensitivity)
-    logits = gemm.linear(x, head, accum="f32")
-    return shard(logits.astype(jnp.float32), "batch", None, "model")
+    logits = gemm.linear(x, head, accum="f32").astype(jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return shard(logits, "batch", None, "model")
 
 
 # ---------------------------------------------------------------------------
@@ -304,7 +308,8 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       q_positions: Optional[jnp.ndarray] = None,
                       kv_valid: Optional[jnp.ndarray] = None,
                       k_positions: Optional[jnp.ndarray] = None,
-                      chunk: int = 512) -> jnp.ndarray:
+                      chunk: int = 512,
+                      scale: Optional[float] = None) -> jnp.ndarray:
     """Exact attention, scanned over query chunks to bound peak memory.
 
     q: [B,Sq,H,D]; k/v: [B,Skv,Hkv,D]. Query position i maps to absolute
@@ -312,12 +317,14 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     ``k_positions`` ([B,Skv] absolute, for rotated SWA caches) defaults to
     arange. ``kv_valid``: [B,Skv] bool for ragged caches. Attention pattern:
     causal (+ sliding window) with an optional bidirectional prefix
-    (prefix-LM, used by the VLM family).
+    (prefix-LM, used by the VLM family). ``scale`` multiplies the scores
+    (default ``1/sqrt(D)``).
     """
     b, sq, h, d = q.shape
     _, skv, hkv, _ = k.shape
     group = h // hkv
-    scale = 1.0 / math.sqrt(d)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     if k_positions is None:
         k_positions = jnp.broadcast_to(jnp.arange(skv)[None], (b, skv))
     # K/V stay in their storage dtype; the contractions below request f32

@@ -127,12 +127,14 @@ def apply_ssm(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     z, xin, b, c, dt = jnp.split(proj, [di, 2 * di, 2 * di + n, 2 * di + 2 * n],
                                  axis=-1)
     conv_in = jnp.concatenate([xin, b, c], axis=-1)
-    conv_out = jax.nn.silu(_causal_conv(conv_in.astype(jnp.float32),
-                                        p["conv_w"], p["conv_b"]))
+    with jax.named_scope("ssm_conv"):
+        conv_out = jax.nn.silu(_causal_conv(conv_in.astype(jnp.float32),
+                                            p["conv_w"], p["conv_b"]))
     xin, b, c = jnp.split(conv_out, [di, di + n], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     xh = xin.reshape(bsz, s, nh, hp)
-    y, final_state = ssd_chunked(xh, dt, p["A_log"], b, c, cfg.ssm_chunk)
+    with jax.named_scope("ssm_scan"):
+        y, final_state = ssd_chunked(xh, dt, p["A_log"], b, c, cfg.ssm_chunk)
     y = y + p["D"][None, None, :, None] * xh          # skip connection
     y = y.reshape(bsz, s, di)
     y = rms_norm_gated(y, z.astype(jnp.float32), p["norm"])
@@ -171,17 +173,22 @@ def decode_ssm(cfg: ModelConfig, p: dict, x: jnp.ndarray,
     z, xin, b, c, dt = jnp.split(proj, [di, 2 * di, 2 * di + n, 2 * di + 2 * n],
                                  axis=-1)
     conv_in = jnp.concatenate([xin, b, c], axis=-1).astype(jnp.float32)
-    window = jnp.concatenate([cache["conv"], conv_in[:, None]], axis=1)
-    conv_out = jax.nn.silu((window * p["conv_w"][None]).sum(1) + p["conv_b"])
+    with jax.named_scope("ssm_conv"):
+        window = jnp.concatenate([cache["conv"], conv_in[:, None]], axis=1)
+        conv_out = jax.nn.silu((window * p["conv_w"][None]).sum(1)
+                               + p["conv_b"])
+        new_conv = window[:, 1:]
     xin, b, c = jnp.split(conv_out, [di, di + n], axis=-1)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])     # [B,nh]
-    da = jnp.exp(dt * (-jnp.exp(p["A_log"])))                        # [B,nh]
     xh = xin.reshape(bsz, nh, hp)
-    # state <- decay * state + dt * x (outer) B
-    new_state = (cache["state"] * da[..., None, None]
-                 + jnp.einsum("bhp,bn,bh->bhpn", xh, b, dt))
-    y = jnp.einsum("bhpn,bn->bhp", new_state, c) + p["D"][None, :, None] * xh
+    with jax.named_scope("ssm_state"):
+        da = jnp.exp(dt * (-jnp.exp(p["A_log"])))                    # [B,nh]
+        # state <- decay * state + dt * x (outer) B
+        new_state = (cache["state"] * da[..., None, None]
+                     + jnp.einsum("bhp,bn,bh->bhpn", xh, b, dt))
+        y = (jnp.einsum("bhpn,bn->bhp", new_state, c)
+             + p["D"][None, :, None] * xh)
     y = y.reshape(bsz, di)
     y = rms_norm_gated(y, z.astype(jnp.float32), p["norm"])
     out = gemm.linear(y.astype(x.dtype), resolve_weight(p["out_proj"], x.dtype))
-    return out[:, None], {"state": new_state, "conv": window[:, 1:]}
+    return out[:, None], {"state": new_state, "conv": new_conv}

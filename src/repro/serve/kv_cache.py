@@ -38,9 +38,27 @@ Block-accounting contract
   preempt-resume contracts ride on this).
 
 Supported families: decoder-only token LMs with full attention (dense / moe /
-parallel-block). Sliding-window rings, SSM state, and encoder-decoder caches
-are not paged here (the ring wrap and non-KV state break the block mapping);
-constructing a :class:`PagedKVCache` for one raises ``ValueError``.
+parallel-block), and layer-typed hybrids (``ModelConfig.layer_types``:
+mamba and attention layers, one mixer each). Sliding-window rings,
+encoder-decoder and VLM caches, models with no attention layer and hybrids
+that run both mixers in every layer are not paged here (the ring wrap and the other
+state break the block mapping); constructing a :class:`PagedKVCache` for
+one raises ``ValueError``.
+
+Recurrent state (layer-typed hybrids)
+=====================================
+
+Only the attention layers are paged (``L`` is their count). Beside the pool
+lives a per-row recurrent store, ``ssm["state"]: [Lm, max_live, H, P, N]``
+and ``ssm["conv"]: [Lm, max_live, W - 1, C]`` (float32, what the model's
+decode reads and writes; ``Lm`` mamba layers). A row's state is written
+whole, never in part: by ``insert_dense`` (the state prefill or a replay
+produced), by ``write_position`` (a batch-1 decode's new state, with its
+written position) and by the batched step, which reads every row's state
+and returns every row's new state. A dead row's state is bitwise inert:
+admission overwrites it before a live row reads it, and rows never mix, so
+``release`` leaves it. The store's bytes count in ``pool_bytes``
+(``state_bytes``), one memory account with the blocks.
 
 Quantized pool (``quantize="int8"``)
 ====================================
@@ -75,6 +93,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.attention import PagedKV
+from repro.models.ssm import init_ssm_cache
 from repro.testing import faults
 
 # The two paged leaves of a decoder-only attention cache.
@@ -100,6 +119,19 @@ def _scrub_row(pool, row):
     zeros = jnp.zeros((pool.shape[0], row.shape[0], *pool.shape[2:]),
                       pool.dtype)
     return pool.at[:, row].set(zeros)
+
+
+# A row of the recurrent store is written in place (donated): the store is
+# whole-model sized, and a copying write per admission would hold two.
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_state_row(store, row, leaf):
+    return store.at[:, row].set(leaf[:, 0])
+
+
+@jax.jit
+def _state_row(store, row):
+    return jax.lax.dynamic_slice_in_dim(store, row, 1, axis=1)
 
 
 @jax.jit
@@ -261,13 +293,18 @@ class PagedKVCache:
     def __init__(self, model_cfg, *, max_live: int, max_len: int,
                  block_size: int, num_blocks: int, cache_dtype="float32",
                  quantize: Optional[str] = None):
-        if model_cfg.is_encoder_decoder or model_cfg.has_ssm \
-                or model_cfg.family == "vlm" or not model_cfg.has_attention \
-                or model_cfg.attention_type == "sliding_window":
+        if model_cfg.is_encoder_decoder or model_cfg.family == "vlm" \
+                or model_cfg.num_attention_layers == 0 \
+                or model_cfg.attention_type == "sliding_window" \
+                or (model_cfg.has_ssm and not model_cfg.layer_typed):
             raise ValueError(
-                "paged KV supports decoder-only full-attention token LMs "
-                f"(family {model_cfg.family!r}, attention "
-                f"{model_cfg.attention_type!r} not pageable)")
+                f"{model_cfg.name!r} is not pageable (family "
+                f"{model_cfg.family!r}, attention "
+                f"{model_cfg.attention_type!r}, encoder-decoder "
+                f"{model_cfg.is_encoder_decoder}, state-space layers "
+                f"{'in every layer' if model_cfg.has_ssm else 'none'}): "
+                "paged KV holds decoder-only full-attention token LMs and "
+                "layer-typed hybrids (mamba and attention layers)")
         if max_len % block_size != 0:
             raise ValueError(f"max_len={max_len} must be a multiple of "
                              f"block_size={block_size} (a slot's blocks must "
@@ -283,7 +320,7 @@ class PagedKVCache:
         self.quantize = quantize
         self.compute_dtype = jnp.dtype(cache_dtype)
         dtype = jnp.dtype(jnp.int8) if quantize else self.compute_dtype
-        L = model_cfg.num_layers
+        L = model_cfg.num_attention_layers
         pool_shape = (L, num_blocks + 1, block_size,
                       model_cfg.num_kv_heads, model_cfg.head_dim)
         self.pool: Dict[str, jnp.ndarray] = {
@@ -294,6 +331,15 @@ class PagedKVCache:
         if quantize:
             self.scales = {name: jnp.ones(pool_shape[:3], jnp.float32)
                            for name in _KV_LEAVES}
+        # The recurrent store (layer-typed hybrids; None otherwise): each
+        # row's state, as the model's decode caches hold it.
+        self.ssm: Optional[Dict[str, jnp.ndarray]] = None
+        if model_cfg.layer_typed:
+            one = jax.eval_shape(
+                lambda: init_ssm_cache(model_cfg, max_live, jnp.float32))
+            self.ssm = {name: jnp.zeros((model_cfg.num_ssm_layers,
+                                         *leaf.shape), leaf.dtype)
+                        for name, leaf in one.items()}
         # Host-side: per-slot block lists (allocation order == position
         # order) and the dense table the jit'd step consumes.
         self._slot_blocks: List[List[int]] = [[] for _ in range(max_live)]
@@ -318,10 +364,7 @@ class PagedKVCache:
                 and self.alloc.free_count + self.alloc.used_count
                 == self.alloc.capacity)
 
-    def pool_bytes(self) -> int:
-        """Device bytes resident in the KV pool: value leaves plus, for a
-        quantized pool, the per-position scale leaves (the honest total a
-        block budget must cover)."""
+    def _kv_bytes(self) -> int:
         total = sum(leaf.size * leaf.dtype.itemsize
                     for leaf in self.pool.values())
         if self.scales is not None:
@@ -329,10 +372,23 @@ class PagedKVCache:
                          for s in self.scales.values())
         return total
 
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent store (0 without one)."""
+        if self.ssm is None:
+            return 0
+        return sum(leaf.size * leaf.dtype.itemsize
+                   for leaf in self.ssm.values())
+
+    def pool_bytes(self) -> int:
+        """Device bytes resident in the KV pool: value leaves plus, for a
+        quantized pool, the per-position scale leaves, plus the recurrent
+        store (the honest total a memory budget must cover)."""
+        return self._kv_bytes() + self.state_bytes()
+
     def bytes_per_block(self) -> int:
-        """Pool bytes per (layer-stacked) block — the per-token KV cost is
+        """KV bytes per (layer-stacked) block — the per-token KV cost is
         this divided by ``block_size``."""
-        return self.pool_bytes() // (self.alloc.capacity + 1)
+        return self._kv_bytes() // (self.alloc.capacity + 1)
 
     # ----- allocation / release -------------------------------------------
 
@@ -385,7 +441,9 @@ class PagedKVCache:
         receive the dense cache's zero padding, so the null block stays
         zero — one compiled scatter regardless of how many blocks are live.
         A quantized pool quantizes each position here, exactly once (zero
-        padding rounds to zero values with scale 1.0)."""
+        padding rounds to zero values with scale 1.0). A recurrent store
+        takes the row's state from ``caches["ssm"]``."""
+        self._put_state(slot, caches)
         row = jnp.asarray(self.tables[slot])
         for name in _KV_LEAVES:
             leaf = caches["kv"][name]
@@ -399,11 +457,13 @@ class PagedKVCache:
 
     def write_position(self, slot: int, pos: int, caches) -> None:
         """Commit ONE written position from a batch-1 decode's new caches
-        into the slot's block (the bisection path's per-row commit)."""
+        into the slot's block (the bisection path's per-row commit), and
+        its new recurrent state into the slot's row."""
         block = self.tables[slot, pos // self.block_size]
         if block == 0:
             raise ValueError(f"slot {slot} position {pos} not backed by an "
                              "allocated block")
+        self._put_state(slot, caches)
         dest = int(block) * self.block_size + pos % self.block_size
         for name in _KV_LEAVES:
             written = caches["kv"][name][:, 0, pos]     # [L, Hkv, D]
@@ -415,8 +475,15 @@ class PagedKVCache:
                 self.pool[name] = _write_pos(self.pool[name], jnp.int32(dest),
                                              written)
 
+    def _put_state(self, slot: int, caches) -> None:
+        if self.ssm is not None:
+            for name, leaf in caches["ssm"].items():
+                self.ssm[name] = _put_state_row(self.ssm[name],
+                                                jnp.int32(slot), leaf)
+
     def gather_slot(self, slot: int) -> dict:
-        """The slot's dense batch-1 cache view ``{"kv": {"k", "v"}}`` —
+        """The slot's dense batch-1 cache view ``{"kv": {"k", "v"}}`` (with
+        ``"ssm"``, the row's recurrent state, where there is a store) —
         bitwise the cache the batch-1 programs would hold (bisection re-runs
         and tests read through this). Quantized pools dequantize into the
         compute dtype — elementwise ``q * scale``, so the view is bitwise
@@ -424,12 +491,17 @@ class PagedKVCache:
         row = jnp.asarray(self.tables[slot])
         if self.quantize:
             dt = self.compute_dtype.name
-            return {"kv": {name: _gather_row_q(self.pool[name],
-                                               self.scales[name], row,
-                                               dtype=dt)
-                           for name in _KV_LEAVES}}
-        return {"kv": {name: _gather_row(self.pool[name], row)
-                       for name in _KV_LEAVES}}
+            out = {"kv": {name: _gather_row_q(self.pool[name],
+                                              self.scales[name], row,
+                                              dtype=dt)
+                          for name in _KV_LEAVES}}
+        else:
+            out = {"kv": {name: _gather_row(self.pool[name], row)
+                          for name in _KV_LEAVES}}
+        if self.ssm is not None:
+            out["ssm"] = {name: _state_row(leaf, jnp.int32(slot))
+                          for name, leaf in self.ssm.items()}
+        return out
 
     def device_tables(self) -> jnp.ndarray:
         """The block table as a device operand for the jit'd batched step

@@ -52,7 +52,10 @@ it holds ``serve.prefill`` and the first token's ``serve.token_wait`` and
 ``serve.token_wait`` (the host blocked on the chip) and ``serve.commit``.
 The batched step names its KV traffic ``kv_gather`` (each layer's read of
 its blocks) and ``kv_scatter`` (the written positions put into the pool)
-(``jax.named_scope``).
+(``jax.named_scope``); a layer-typed hybrid's step names its recurrent
+update ``ssm_state`` (each mamba layer's state read, decayed, updated and
+written) and ``ssm_conv`` (its conv window), and its prefill's chunked scan
+``ssm_scan``.
 """
 from __future__ import annotations
 
@@ -172,39 +175,53 @@ class ContinuousScheduler:
         0, pos 0) compute identical garbage and land their write in the null
         block — masked everywhere, bitwise inert.
 
-        A quantized pool (``cfg.kv_quantize``) threads the per-position
-        scale leaves through the same program: the read dequantizes ``q *
-        scale`` into the compute dtype (elementwise, so each row reads
-        bitwise ``gather_slot``'s view), and the scatter quantizes the
-        written positions with the shared ``kv_cache.quantize_kv_position``
-        formula — the same bytes a batch-1 ``write_position`` of that vector
-        would commit."""
+        ``(params, pool_k, pool_v, [scale_k, scale_v,] [ssm_state,
+        ssm_conv,] tables, tokens, pos) -> (logits, pool_k, pool_v,
+        [scale_k, scale_v,] [ssm_state, ssm_conv])``. A quantized pool
+        (``cfg.kv_quantize``) threads the per-position scale leaves through
+        the same program: the read dequantizes ``q * scale`` into the
+        compute dtype (elementwise, so each row reads bitwise
+        ``gather_slot``'s view), and the scatter quantizes the written
+        positions with the shared ``kv_cache.quantize_kv_position`` formula
+        — the same bytes a batch-1 ``write_position`` of that vector would
+        commit. A recurrent store (``kv.ssm``) goes in whole and comes back
+        whole: every row's state read, updated and written by the model's
+        decode, as each row's batch-1 decode would."""
         from repro.serve.kv_cache import _write_pos, _write_pos_q, pool_view
         model = self.engine.model
         B = self.cfg.max_live
         bs = self.kv.block_size
         compute_dtype = self.kv.compute_dtype.name
+        quantized = bool(self.cfg.kv_quantize)
+        recurrent = self.kv.ssm is not None
 
-        def step(params, pool_k, pool_v, tables, tokens, pos):
-            logits, new = model.decode(
-                params, pool_view(pool_k, pool_v, tables), tokens, pos)
-            dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
-            with jax.named_scope("kv_scatter"):
-                return (logits[:, 0], _write_pos(pool_k, dest, new["kv"]["k"]),
-                        _write_pos(pool_v, dest, new["kv"]["v"]))
-
-        def step_q(params, pool_k, pool_v, scale_k, scale_v, tables,
-                   tokens, pos):
-            view = pool_view(pool_k, pool_v, tables, scale_k, scale_v,
+        def step(params, pool_k, pool_v, *rest):
+            scales, rest = (rest[:2], rest[2:]) if quantized else ((), rest)
+            ssm, rest = (({"state": rest[0], "conv": rest[1]}, rest[2:])
+                         if recurrent else (None, rest))
+            tables, tokens, pos = rest
+            view = pool_view(pool_k, pool_v, tables, *scales,
                              dtype=compute_dtype)
-            logits, new = model.decode(params, view, tokens, pos)
+            caches = view if ssm is None else {"kv": view, "ssm": ssm}
+            logits, new = model.decode(params, caches, tokens, pos)
             dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
-            with jax.named_scope("kv_scatter"):
-                pk, sk = _write_pos_q(pool_k, scale_k, dest, new["kv"]["k"])
-                pv, sv = _write_pos_q(pool_v, scale_v, dest, new["kv"]["v"])
-            return logits[:, 0], pk, pv, sk, sv
+            if quantized:
+                with jax.named_scope("kv_scatter"):
+                    pk, sk = _write_pos_q(pool_k, scales[0], dest,
+                                          new["kv"]["k"])
+                    pv, sv = _write_pos_q(pool_v, scales[1], dest,
+                                          new["kv"]["v"])
+                out = (logits[:, 0], pk, pv, sk, sv)
+            else:
+                with jax.named_scope("kv_scatter"):
+                    out = (logits[:, 0],
+                           _write_pos(pool_k, dest, new["kv"]["k"]),
+                           _write_pos(pool_v, dest, new["kv"]["v"]))
+            if recurrent:
+                out += (new["ssm"]["state"], new["ssm"]["conv"])
+            return out
 
-        return jax.jit(step_q if self.cfg.kv_quantize else step)
+        return jax.jit(step)
 
     # ----- admission ------------------------------------------------------
 
@@ -542,18 +559,14 @@ class ContinuousScheduler:
                 try:
                     faults.maybe_fail("batch_step")
                     kv = self.kv
+                    args = [self.engine.params, kv.pool["k"], kv.pool["v"]]
                     if kv.quantize:
-                        logits, pk, pv, sk, sv = self._jit_step(
-                            self.engine.params, kv.pool["k"], kv.pool["v"],
-                            kv.scales["k"], kv.scales["v"],
-                            kv.device_tables(), jnp.asarray(tokens),
-                            jnp.asarray(pos))
-                    else:
-                        sk = sv = None
-                        logits, pk, pv = self._jit_step(
-                            self.engine.params, kv.pool["k"], kv.pool["v"],
-                            kv.device_tables(), jnp.asarray(tokens),
-                            jnp.asarray(pos))
+                        args += [kv.scales["k"], kv.scales["v"]]
+                    if kv.ssm is not None:
+                        args += [kv.ssm["state"], kv.ssm["conv"]]
+                    out = self._jit_step(*args, kv.device_tables(),
+                                         jnp.asarray(tokens),
+                                         jnp.asarray(pos))
                 except Exception as exc:  # noqa: BLE001 — retry, bisect
                     cause = health.classify_failure(exc)
                     if cause in RETRYABLE_CLASSES \
@@ -577,9 +590,12 @@ class ContinuousScheduler:
             return
         # Commit only after a clean shared step (retries/bisection never see
         # a half-mutated pool — the jit'd step returned NEW pool arrays).
-        self.kv.pool["k"], self.kv.pool["v"] = pk, pv
-        if sk is not None:
-            self.kv.scales["k"], self.kv.scales["v"] = sk, sv
+        logits, self.kv.pool["k"], self.kv.pool["v"], *rest = out
+        if self.kv.quantize:
+            (self.kv.scales["k"], self.kv.scales["v"]), rest = rest[:2], \
+                rest[2:]
+        if self.kv.ssm is not None:
+            self.kv.ssm["state"], self.kv.ssm["conv"] = rest
         self._commit_rows(done, live_rows, logits)
 
     def _bisect(self, done: Dict[int, RequestResult], cause, exc) -> None:
