@@ -3,12 +3,15 @@
 Projections go through ``repro.core.gemm.linear`` (the paper's layered GEMM);
 the score/value contractions use the memory-bounded chunked lowering from
 ``layers.chunked_attention`` (TPU fast path: ``repro.kernels.flash_attention``,
-same oracle).
+same oracle). One-token decode over a full-attention cache, dense or paged,
+goes through the blocked decode attention of
+``repro.kernels.paged_attention``.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Optional, Tuple
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +19,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
 from repro.core import gemm
+from repro.core.contraction import kernel_backend
+from repro.kernels import paged_attention
 from repro.models.layers import (apply_rope, chunked_attention, dense_param,
                                  resolve_weight)
 from repro.parallel.mesh import shard
@@ -152,44 +157,123 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def dequantize_kv(q, scale, dtype):
+    """Elementwise ``q * scale`` into the compute dtype (scale broadcasts
+    over the trailing [Hkv, D] axes): an int8 KV pool's one read formula."""
+    return (q.astype(jnp.float32) * scale[..., None, None]).astype(dtype)
+
+
 @dataclasses.dataclass(frozen=True)
 class PagedKV:
     """A block pool of K/V, read one layer at a time by the batched decode.
 
-    ``read(layer)`` returns that layer's ``(k, v)``, each ``[B, max_len,
-    Hkv, D]`` in the compute dtype: every row's blocks in table order,
-    bitwise the dense cache of that row. ``layer`` is bound inside the
-    decode layer scan. Decoding through a ``PagedKV`` writes nothing back:
-    each layer returns only the position it wrote, for the caller to put
-    into the pool.
+    ``k``/``v``: the pool leaves ``[L, blocks, bs, Hkv, D]``; ``tables``:
+    each row's block ids ``[B, MB]``; ``layer``: bound inside the decode
+    layer scan. An int8 pool carries its per-position scales ``[L, blocks,
+    bs]`` and the compute ``dtype`` it dequantizes into. Decoding through a
+    ``PagedKV`` writes nothing back: each layer returns only the position
+    it wrote, for the caller to put into the pool.
     """
 
-    read: Callable[[jnp.ndarray], Tuple[jnp.ndarray, jnp.ndarray]]
+    k: jnp.ndarray
+    v: jnp.ndarray
+    tables: jnp.ndarray
+    scale_k: Optional[jnp.ndarray] = None
+    scale_v: Optional[jnp.ndarray] = None
+    dtype: Optional[str] = None
     layer: Optional[jnp.ndarray] = None
+
+    @jax.named_scope("kv_gather")
+    def dense(self) -> dict:
+        """An int8 pool's layer as dense ``{"k", "v"}`` ``[B, max_len, Hkv,
+        D]`` in ``dtype``: each row's blocks in table order, dequantized
+        elementwise, bitwise the view ``PagedKVCache.gather_slot`` gives."""
+        def leaf(pool, scales):
+            g = dequantize_kv(pool[self.layer, self.tables],
+                              scales[self.layer, self.tables], self.dtype)
+            return g.reshape(self.tables.shape[0], -1, *pool.shape[3:])
+
+        return {"k": leaf(self.k, self.scale_k),
+                "v": leaf(self.v, self.scale_v)}
+
+
+def blocked_decode_attention(q, k_new, v_new, pool_k, pool_v, layer, tables,
+                             lens, *, scale: float):
+    """One-token attention of each row over positions ``0 .. lens - 1`` of
+    its pool blocks, then its new position, in the reduction order of
+    :mod:`repro.kernels.paged_attention`: the Pallas kernel on a TPU
+    (outside a device mesh), its jnp twin elsewhere. q: [B, H, D]."""
+    fn = (paged_attention.paged_decode_attention if kernel_backend()
+          else paged_attention.paged_decode_attention_jnp)
+    with jax.named_scope("attention"):
+        return fn(q, k_new, v_new, pool_k, pool_v, layer, tables, lens,
+                  scale=scale)
 
 
 def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                      cache, pos: jnp.ndarray) -> Tuple[jnp.ndarray, dict]:
     """One-token self attention. x: [B,1,d]; pos: [B] absolute position.
 
-    The cache is a ring buffer of ``slots`` positions: slot s holds absolute
-    position  p(s) = pos - ((pos - s) mod slots)  (the most recent position
-    congruent to s). Masking reconstructs absolute positions from slot ids, so
-    sliding windows need no rolls — the paper's "packing" discipline applied
-    to the KV stream: write once, contiguous layout, no data motion.
+    ``cache`` is a dense ``{"k", "v"}`` cache ``[B, slots, Hkv, D]``,
+    returned with the new position written; or a :class:`PagedKV` bound to
+    a layer, for which only the written ``{"k", "v"}`` ``[B, Hkv, D]`` is
+    returned.
 
-    ``cache`` is a dense ``{"k", "v"}`` ring ``[B, slots, Hkv, D]``, returned
-    with the new position written; or a :class:`PagedKV` bound to a layer,
-    read here, for which only the written ``{"k", "v"}`` ``[B, Hkv, D]`` is
-    returned. Both put the new position in place by the same select, so a
-    paged row attends over exactly the values its dense cache would hold.
+    Full attention reads positions ``0 .. pos - 1`` and the new position
+    through :func:`blocked_decode_attention`, from the pool's blocks or
+    from the dense cache viewed as blocks
+    (``paged_attention.dense_tables``), so a paged row attends in exactly
+    the order and over exactly the values of its dense cache. A sliding
+    window keeps a ring of ``window`` slots: slot s holds absolute position
+    p(s) = pos - ((pos - s) mod slots) (the most recent position congruent
+    to s), and masking reconstructs absolute positions from slot ids, so
+    the window needs no rolls — the paper's "packing" discipline applied to
+    the KV stream: write once, contiguous layout, no data motion.
     """
     b = x.shape[0]
     window = cfg.sliding_window if cfg.attention_type == "sliding_window" else None
     q, k_new, v_new = project_qkv(cfg, p, x, pos[:, None])
+    if window:
+        return _ring_attention(cfg, p, x, q, k_new, v_new, cache, pos,
+                               window)
     paged = isinstance(cache, PagedKV)
-    k_old, v_old = cache.read(cache.layer) if paged else (cache["k"],
-                                                          cache["v"])
+    if paged and cache.scale_k is None:
+        pool_k, pool_v = cache.k, cache.v
+        layer, tables = cache.layer, cache.tables
+    else:
+        dense = cache.dense() if paged else cache
+        max_len = dense["k"].shape[1]
+        bs, tables = paged_attention.dense_tables(b, max_len)
+        pool_k, pool_v = (dense[n].reshape(1, -1, bs, *dense[n].shape[2:])
+                          for n in ("k", "v"))
+        layer = 0
+    k_new = k_new[:, 0].astype(pool_k.dtype)
+    v_new = v_new[:, 0].astype(pool_v.dtype)
+    scale = cfg.attention_multiplier
+    out = blocked_decode_attention(
+        q[:, 0], k_new, v_new, pool_k, pool_v, layer, tables, pos,
+        scale=1.0 / math.sqrt(cfg.head_dim) if scale is None else scale)
+    out = out.reshape(b, 1, cfg.q_dim)
+    out = gemm.linear(out, resolve_weight(p["wo"], x.dtype), p.get("bo"))
+    if paged:
+        return out, {"k": k_new, "v": v_new}
+    at = (jnp.arange(max_len)[None, :] == pos[:, None])[:, :, None, None]
+
+    @jax.named_scope("kv_write")
+    def write(buf, new):
+        return jnp.where(at, new[:, None], buf)
+
+    return out, {"k": shard(write(cache["k"], k_new), "batch", "kv_seq"),
+                 "v": shard(write(cache["v"], v_new), "batch", "kv_seq")}
+
+
+def _ring_attention(cfg: ModelConfig, p: dict, x, q, k_new, v_new, cache,
+                    pos, window: int):
+    """A sliding window's decode over its dense ring ``[B, slots, Hkv, D]``
+    (see :func:`decode_attention`): the new position written by a select,
+    then attention over every slot, masked to the window."""
+    b = x.shape[0]
+    k_old, v_old = cache["k"], cache["v"]
     k_new, v_new = k_new.astype(k_old.dtype), v_new.astype(v_old.dtype)
     slots = k_old.shape[1]
     slot_ids = jnp.arange(slots)[None, :]                      # [1, slots]
@@ -204,10 +288,7 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
 
     posb = pos[:, None]
     k_positions = posb - ((posb - slot_ids) % slots)           # [B, slots]
-    kv_valid = k_positions >= 0
-    if window is not None:
-        kv_valid &= (posb - k_positions) < window
-
+    kv_valid = (k_positions >= 0) & ((posb - k_positions) < window)
     out = chunked_attention(q, k_cache, v_cache, causal=True,
                             q_positions=pos[:, None],
                             k_positions=k_positions,
@@ -215,6 +296,4 @@ def decode_attention(cfg: ModelConfig, p: dict, x: jnp.ndarray,
                             scale=cfg.attention_multiplier)
     out = out.reshape(b, 1, cfg.q_dim)
     out = gemm.linear(out, resolve_weight(p["wo"], x.dtype), p.get("bo"))
-    if paged:
-        return out, {"k": k_new[:, 0], "v": v_new[:, 0]}
     return out, {"k": k_cache, "v": v_cache}

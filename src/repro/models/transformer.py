@@ -507,10 +507,11 @@ def typed_decode(cfg: ModelConfig, params: dict, caches: dict, x, pos):
         return ssm_mod.decode_ssm(cfg, p, h, s)
 
     if paged:
-        k_view, v_view = jax.eval_shape(caches["kv"].read, 0)
+        pool = caches["kv"]
+        dtype = pool.k.dtype if pool.scale_k is None else pool.dtype
         kv = {name: jnp.zeros((cfg.num_attention_layers, b,
-                               *view.shape[2:]), view.dtype)
-              for name, view in (("k", k_view), ("v", v_view))}
+                               *pool.k.shape[3:]), dtype)
+              for name in ("k", "v")}
     else:
         kv = caches["kv"]
     x, kv, ssm = _typed_stack(cfg, params, x, kv, attn_mixer, ssm_mixer,

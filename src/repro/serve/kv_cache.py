@@ -12,10 +12,10 @@ Block-accounting contract
 
 * The pool holds ``num_blocks + 1`` blocks per layer; **block 0 is the NULL
   block** — it backs every unallocated table entry, absorbs the batched
-  step's padding-row writes, and is NEVER validly read: any gathered position
-  it backs lies beyond the owning slot's current length, which the decode
-  attention mask excludes exactly (``-1e30`` masking → probability exactly
-  zero → the value contraction contributes exactly zero; proven in
+  step's padding-row writes, and is NEVER validly read: any position it
+  backs lies beyond the owning slot's current length, which the decode
+  attention never fetches or masks exactly (probability exactly zero, the
+  value zeroed before the value contraction; proven in
   ``tests/test_serve_continuous.py``). Block 0 is never allocated and never
   freed.
 * :class:`BlockAllocator` hands out blocks lowest-id-first (deterministic
@@ -30,12 +30,13 @@ Block-accounting contract
   parked in a recycled block would otherwise leak through the masked value
   contraction (0 · NaN = NaN). After a full drain
   ``allocator.free_count == allocator.capacity`` (property-swept in tests).
-* ``max_len % block_size == 0`` is required so a fully-tabled slot reads as
+* ``max_len % block_size == 0`` is required so a fully-tabled slot holds
   EXACTLY the dense ``max_len`` cache the batch-1 programs use — the batched
-  step's per-layer read of a row's blocks and the dense cache are then the
-  same ring arithmetic, written by the same select, which is what makes the
-  batched step bitwise-equal to the batch-1 path (the bisection and
-  preempt-resume contracts ride on this).
+  step's attention over a row's blocks and the batch-1 attention over its
+  dense cache are the same blocked decode attention, folding the same
+  positions in the same order, which is what makes the batched step
+  bitwise-equal to the batch-1 path (the bisection and preempt-resume
+  contracts ride on this).
 
 Supported families: decoder-only token LMs with full attention (dense / moe /
 parallel-block), and layer-typed hybrids (``ModelConfig.layer_types``:
@@ -92,7 +93,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.attention import PagedKV
+from repro.models.attention import dequantize_kv
 from repro.models.ssm import init_ssm_cache
 from repro.testing import faults
 
@@ -163,12 +164,6 @@ def quantize_kv_position(x):
     return q.astype(jnp.int8), scale
 
 
-def dequantize_kv(q, scale, dtype):
-    """Elementwise ``q * scale`` into the compute dtype (scale broadcasts
-    over the trailing [Hkv, D] axes)."""
-    return (q.astype(jnp.float32) * scale[..., None, None]).astype(dtype)
-
-
 @jax.jit
 def _scatter_blocks_q(pool, scales, row, leaf):
     bs = pool.shape[2]
@@ -201,29 +196,6 @@ def _write_pos_q(pool, scales, dest, written):
     sflat = scales.reshape(scales.shape[0], -1)
     return (flat.at[:, dest].set(q).reshape(pool.shape),
             sflat.at[:, dest].set(s).reshape(scales.shape))
-
-
-def pool_view(pool_k, pool_v, tables, scale_k=None, scale_v=None, *,
-              dtype=None) -> PagedKV:
-    """The batched decode step's read of the pool, one layer at a time.
-
-    Inside the layer scan, ``read(layer)`` gathers that layer's blocks of
-    every row by ``tables`` straight from the pool (one gather indexed by
-    the layer number, so no per-layer pool slice is written) into ``[B,
-    max_len, Hkv, D]``; a quantized pool dequantizes them elementwise into
-    ``dtype``, as ``gather_slot`` does, so each row reads bitwise its dense
-    batch-1 cache."""
-    def leaf(pool, scales, layer):
-        g = pool[layer, tables]                  # [B, MB, bs, Hkv, D]
-        if scales is not None:
-            g = dequantize_kv(g, scales[layer, tables], dtype)
-        return g.reshape(tables.shape[0], -1, *pool.shape[3:])
-
-    @jax.named_scope("kv_gather")
-    def read(layer):
-        return leaf(pool_k, scale_k, layer), leaf(pool_v, scale_v, layer)
-
-    return PagedKV(read)
 
 
 class BlockAllocator:
@@ -279,11 +251,12 @@ class PagedKVCache:
     ``pool[name]: [L, num_blocks + 1, block_size, Hkv, D]`` for ``name`` in
     ``("k", "v")`` — plus a HOST block table ``tables: [max_live,
     blocks_per_slot] int32`` mapping each slot's position range onto pool
-    blocks (0 = null block). The batched decode step reads the pool one
-    layer at a time inside the model's layer scan (:func:`pool_view`: that
-    layer's blocks of every row, by ``tables``) and scatters back only the
-    one position each row wrote, ``[L, B, Hkv, D]``; no dense ``[L, B,
-    max_len, Hkv, D]`` view of all layers is built.
+    blocks (0 = null block). The batched decode step's attention reads each
+    row's live blocks of a layer straight from the pool
+    (``models.attention.PagedKV``, ``kernels.paged_attention``) and the
+    step scatters back only the one position each row wrote, ``[L, B, Hkv,
+    D]``; no dense ``[B, max_len, Hkv, D]`` view is built (an int8 pool is
+    read as one, dequantized, a layer at a time).
 
     ``quantize="int8"`` stores the pool as int8 values + per-position f32
     scale leaves (see the module docstring's quantized-pool contract);

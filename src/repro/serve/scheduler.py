@@ -50,12 +50,14 @@ phases in order: ``serve.admit`` per admission (argument ``request_id``;
 it holds ``serve.prefill`` and the first token's ``serve.token_wait`` and
 ``serve.commit``), ``serve.kv_grow``, ``serve.step_dispatch``,
 ``serve.token_wait`` (the host blocked on the chip) and ``serve.commit``.
-The batched step names its KV traffic ``kv_gather`` (each layer's read of
-its blocks) and ``kv_scatter`` (the written positions put into the pool)
-(``jax.named_scope``); a layer-typed hybrid's step names its recurrent
-update ``ssm_state`` (each mamba layer's state read, decayed, updated and
-written) and ``ssm_conv`` (its conv window), and its prefill's chunked scan
-``ssm_scan``.
+The batched step's attention (scope ``attention``) reads each row's live
+blocks from the pool (the ``paged_decode_attention`` kernel on a TPU), and
+the step names its KV traffic ``kv_scatter`` (the written positions put
+into the pool) and, for an int8 pool, ``kv_gather`` (each layer's
+dequantized read) (``jax.named_scope``); a layer-typed hybrid's step
+names its recurrent update ``ssm_state`` (each mamba layer's state read,
+decayed, updated and written) and ``ssm_conv`` (its conv window), and its
+prefill's chunked scan ``ssm_scan``.
 """
 from __future__ import annotations
 
@@ -71,6 +73,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core import health
+from repro.models.attention import PagedKV
 from repro.serve.frontend import RETRYABLE_CLASSES, VirtualClock  # noqa: F401
 from repro.serve.kv_cache import PagedKVCache
 from repro.serve.requests import Overloaded, Request, RequestResult
@@ -156,6 +159,11 @@ class ContinuousScheduler:
         self.results: Dict[int, RequestResult] = {}
         self._seen: set = set()
         self._admit_seq = 0
+        # Blocks the batched steps' attention visited (each row's blocks
+        # below its length, per layer) and those a dense view of every row
+        # would have read: the paged read's engagement, in ``stats()``.
+        self._kv_blocks_visited = 0
+        self._kv_blocks_dense = 0
         key = (cfg.max_live, max_len, cfg.block_size, cfg.kv_quantize)
         cache = _STEP_CACHE.setdefault(engine, {})
         if key not in cache:
@@ -166,28 +174,29 @@ class ContinuousScheduler:
 
     def _build_step(self):
         """One jit'd program for the whole batch, compiled ONCE. Inside the
-        model's layer scan each layer reads its rows' blocks straight from
-        the pool (``kv_cache.pool_view``, scope ``kv_gather``), puts the new
-        position in place by a select and attends; the scan emits only the
-        written positions ``[L, B, Hkv, D]``, and one scatter per leaf
-        (``kv_scatter``) writes them into a new pool. No dense ``[L, B,
-        max_len, Hkv, D]`` view is built. Dead rows (all-null tables, token
-        0, pos 0) compute identical garbage and land their write in the null
-        block — masked everywhere, bitwise inert.
+        model's layer scan each layer attends every row over its live
+        blocks, read straight from the pool
+        (``models.attention.PagedKV``), with the new position folded in
+        last; the scan emits only the written positions ``[L, B, Hkv, D]``,
+        and one scatter per leaf (``kv_scatter``) writes them into a new
+        pool. No dense ``[B, max_len, Hkv, D]`` view is built. Dead rows
+        (all-null tables, token 0, pos 0) read no block, compute identical
+        garbage and land their write in the null block — bitwise inert.
 
         ``(params, pool_k, pool_v, [scale_k, scale_v,] [ssm_state,
         ssm_conv,] tables, tokens, pos) -> (logits, pool_k, pool_v,
         [scale_k, scale_v,] [ssm_state, ssm_conv])``. A quantized pool
         (``cfg.kv_quantize``) threads the per-position scale leaves through
-        the same program: the read dequantizes ``q * scale`` into the
-        compute dtype (elementwise, so each row reads bitwise
-        ``gather_slot``'s view), and the scatter quantizes the written
+        the same program: each layer reads its rows' blocks as a dense
+        view, dequantized ``q * scale`` into the compute dtype (elementwise,
+        so each row reads bitwise ``gather_slot``'s view; scope
+        ``kv_gather``), and the scatter quantizes the written
         positions with the shared ``kv_cache.quantize_kv_position`` formula
         — the same bytes a batch-1 ``write_position`` of that vector would
         commit. A recurrent store (``kv.ssm``) goes in whole and comes back
         whole: every row's state read, updated and written by the model's
         decode, as each row's batch-1 decode would."""
-        from repro.serve.kv_cache import _write_pos, _write_pos_q, pool_view
+        from repro.serve.kv_cache import _write_pos, _write_pos_q
         model = self.engine.model
         B = self.cfg.max_live
         bs = self.kv.block_size
@@ -200,8 +209,8 @@ class ContinuousScheduler:
             ssm, rest = (({"state": rest[0], "conv": rest[1]}, rest[2:])
                          if recurrent else (None, rest))
             tables, tokens, pos = rest
-            view = pool_view(pool_k, pool_v, tables, *scales,
-                             dtype=compute_dtype)
+            view = PagedKV(pool_k, pool_v, tables, *scales,
+                           dtype=compute_dtype)
             caches = view if ssm is None else {"kv": view, "ssm": ssm}
             logits, new = model.decode(params, caches, tokens, pos)
             dest = tables[jnp.arange(B), pos // bs] * bs + pos % bs  # [B]
@@ -588,6 +597,9 @@ class ContinuousScheduler:
         if failure is not None:
             self._bisect(done, *failure)
             return
+        bs = self.kv.block_size
+        self._kv_blocks_visited += int(np.sum((pos + bs - 1) // bs))
+        self._kv_blocks_dense += self.kv.tables.size
         # Commit only after a clean shared step (retries/bisection never see
         # a half-mutated pool — the jit'd step returned NEW pool arrays).
         logits, self.kv.pool["k"], self.kv.pool["v"], *rest = out
@@ -743,11 +755,17 @@ class ContinuousScheduler:
         """Queue/slot depths, KV-block accounting, and the registry's
         conservation counters. ``preempted_open`` is the transient
         preempted population (in the extended invariant ``admitted ==
-        completed + evicted + deadline_miss + open + preempted_open``)."""
+        completed + evicted + deadline_miss + open + preempted_open``).
+        ``kv_blocks_visited`` / ``kv_blocks_dense``: over the clean batched
+        steps, the blocks of a layer the attention read (each row's blocks
+        below its length) and the blocks a dense view of every row would
+        have read (``max_live`` x blocks per row)."""
         stats = dict(health.SERVE.counters())
         stats["queued"] = sum(1 for e in self._queue if not e.preempted)
         stats["preempted_open"] = sum(1 for e in self._queue if e.preempted)
         stats["live"] = len(self._live)
         stats["kv_blocks_free"] = self.kv.alloc.free_count
         stats["kv_blocks_capacity"] = self.kv.alloc.capacity
+        stats["kv_blocks_visited"] = self._kv_blocks_visited
+        stats["kv_blocks_dense"] = self._kv_blocks_dense
         return stats

@@ -288,18 +288,19 @@ def test_hybrid_step_carries_no_copy_of_the_store(engine):
     assert len(scans) == 1
 
 
-# The dense batched step's jaxpr as the tree before the layer-typed stack
-# came in built it (reduced olmo-1b, two rows, float and int8 pools, with
-# and without packed weights): the olmo cells run the same program.
+# The dense batched step's jaxpr (reduced olmo-1b, two rows, float and int8
+# pools, with and without packed weights), as built since its attention reads
+# the pool's blocks (the blocked decode attention's jnp twin, off the TPU):
+# the layer-typed stack leaves the olmo cells' program as it is.
 DENSE_STEP_SHA256 = {
     (False, None):
-        "8fd5588bc1f263612966ec745247e8eacee31bd6b98ceead7c74a0b2f6bda6a4",
+        "3b69f55bc44d1cde269685f294e3d92db2e3269b8c7ed40ab39fd07d6c247d87",
     (False, "int8"):
-        "ab81c4b7449e7a422af7c49f926f4e3150f9c69f240b64d5ae5ddd35d70410a8",
+        "fed0874e0dcfe625ef1233f20555938cc6393ab1632244020892c709891d77f5",
     (True, None):
-        "507125a180a79a5e2d2b67132e2cb9d23c8d9e81fe295a4e84ca33593b97f90e",
+        "4bcebf572cbc2dc92fbb8e5446d2ae8a416b83abb3c616e8a9672b8ff2dd7d73",
     (True, "int8"):
-        "c019b077daecfddc93013f2f09d014cdcccc044b7de36111d0af75a2ad0ad9d4",
+        "bae77cbb89a331b46c6d0845a0b546ae0f3e87c62fff083cb9da550041acc526",
 }
 
 

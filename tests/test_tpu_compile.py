@@ -3,11 +3,13 @@
 No chip is attached: the TPU compiler compiles for a ``v5e:2x2`` topology
 described inside a fixture, at the published widths the serving path runs —
 olmo-1b's dense projections and LM head (decode M=1, prefill M=512; bf16,
-int8 and int4 column-scaled packed weights), the load-time packer, and
-mixtral-8x22b's grouped and ragged expert GEMMs. Mosaic refuses here what
-interpret mode cannot see: plans over the scoped-VMEM limit, blocks that
-break the (8, 128) rule, vector shapes it cannot lay out. Each test asserts
-that the compiled program holds the Pallas kernel (``tpu_custom_call``).
+int8 and int4 column-scaled packed weights), the load-time packer,
+mixtral-8x22b's grouped and ragged expert GEMMs, and the paged decode
+attention at olmo-1b's and granite-4.0-h-micro's pools. Mosaic refuses
+here what interpret mode cannot see: plans over the scoped-VMEM limit,
+blocks that break the (8, 128) rule, vector shapes it cannot lay out. Each
+test asserts that the compiled program holds the Pallas kernel
+(``tpu_custom_call``).
 
 The topology is described only once a test of this file runs (never at
 import), so every test worker collects the same tests and only the worker
@@ -23,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import dtypes as mdt
 from repro.core.layered import GroupedPackedWeight, PackedWeight
 from repro.core.planner import plan_gemm
-from repro.kernels import gemm_grouped, gemm_packed
+from repro.kernels import gemm_grouped, gemm_packed, paged_attention
 from repro.kernels.pack import pack_b
 
 OLMO_D, OLMO_FF, OLMO_VOCAB = 2048, 8192, 50304        # olmo-1b
@@ -153,6 +155,34 @@ def test_grouped_mixtral_widths(one_chip, quantize, gate, ragged):
 
     text = _compile_text(run, a, counts, *_on(stacks, one_chip))
     assert "tpu_custom_call" in text
+
+
+# Decode attention over the paged KV pool at the cells' widths, block 16:
+# (rows, q heads, KV heads, head dim, layers, blocks per row).
+PAGED = {"olmo-1b": (16, 16, 16, 128, 16, 64),
+         "granite-4.0-h-micro": (32, 32, 8, 64, 4, 64)}
+
+
+@pytest.mark.parametrize("model", sorted(PAGED))
+def test_paged_decode_attention_widths(one_chip, model):
+    """The paged decode-attention kernel, its blocks fetched from the pool
+    by scalar-prefetched tables. Where the head dim fills the lane tile
+    (olmo-1b) the pool is read in place: no copy of it is made."""
+    b, h, hkv, d, layers, mb = PAGED[model]
+    bf16 = jnp.bfloat16
+    pool = (layers, b * mb + 1, 16, hkv, d)
+    shapes = [((b, h, d), bf16), ((b, hkv, d), bf16), ((b, hkv, d), bf16),
+              (pool, bf16), (pool, bf16), ((), jnp.int32),
+              ((b, mb), jnp.int32), ((b,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    text = _compile_text(
+        lambda *a: paged_attention.paged_decode_attention(
+            *a, scale=d ** -0.5, interpret=False), *args)
+    assert "tpu_custom_call" in text
+    if d % 128 == 0:
+        pool_text = "bf16[" + ",".join(map(str, pool)) + "]"
+        assert not [line for line in text.splitlines()
+                    if pool_text in line and " copy(" in line]
 
 
 def test_topology_is_a_v5e(topo):
